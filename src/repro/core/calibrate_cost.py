@@ -374,7 +374,7 @@ def _synthetic(shape, dtype, rng):
     return jnp.asarray(rng.normal(size=shape).astype(dt))
 
 
-def _site_runner(site, *, interpret: bool = True, seed: int = 0):
+def _site_runner(site, *, seed: int = 0):
     """A zero-arg callable executing one planned site's member on
     synthetic operands — the same dispatch ``models/blocks.py`` performs,
     lowered rungs (quantized wrappers) included."""
@@ -388,15 +388,13 @@ def _site_runner(site, *, interpret: bool = True, seed: int = 0):
         w = _synthetic(spec.shapes[1], spec.dtype, rng)
         if lowered:
             from repro.quant.ops import quantized_conv2d
-            return lambda: quantized_conv2d(x, w, bits=bits, ip=ip.name,
-                                            interpret=interpret)
+            return lambda: quantized_conv2d(x, w, bits=bits, ip=ip.name)
         if ip.outputs_per_pass >= 2:
             from repro.kernels.conv2d.ops import conv2d_dual
             x2 = _synthetic(spec.shapes[0], spec.dtype, rng)
-            return lambda: conv2d_dual(x, x2, w, ip=ip.name,
-                                       interpret=interpret)
+            return lambda: conv2d_dual(x, x2, w, ip=ip.name)
         from repro.kernels.conv2d.ops import conv2d
-        return lambda: conv2d(x, w, ip=ip.name, interpret=interpret)
+        return lambda: conv2d(x, w, ip=ip.name)
     if fam == "pool2d":
         x = _synthetic(spec.shapes[0], spec.dtype, rng)
         kw = dict(window=spec.knob("window", (2, 2)),
@@ -405,20 +403,18 @@ def _site_runner(site, *, interpret: bool = True, seed: int = 0):
         if lowered:
             from repro.quant.ops import quantized_pool2d
             return lambda: quantized_pool2d(x, bits=bits, ip=ip.name,
-                                            interpret=interpret, **kw)
+                                            **kw)
         from repro.kernels.pool2d.ops import pool2d
-        return lambda: pool2d(x, ip=ip.name, interpret=interpret, **kw)
+        return lambda: pool2d(x, ip=ip.name, **kw)
     if fam == "activation":
         x = _synthetic(spec.shapes[0], spec.dtype, rng)
         kind = spec.knob("kind", "relu")
         if lowered:
             from repro.quant.ops import quantized_activation
             return lambda: quantized_activation(x, kind=kind, bits=bits,
-                                                ip=ip.name,
-                                                interpret=interpret)
+                                                ip=ip.name)
         from repro.kernels.activation.ops import activation
-        return lambda: activation(x, kind=kind, ip=ip.name,
-                                  interpret=interpret)
+        return lambda: activation(x, kind=kind, ip=ip.name)
     if fam == "cnn_fused":
         x = _synthetic(spec.shapes[0], spec.dtype, rng)
         w = _synthetic(spec.shapes[1], spec.dtype, rng)
@@ -429,25 +425,24 @@ def _site_runner(site, *, interpret: bool = True, seed: int = 0):
         if lowered:
             from repro.quant.ops import quantized_fused_cnn_block
             return lambda: quantized_fused_cnn_block(
-                x, w, bits=bits, ip=ip.name, interpret=interpret, **kw)
+                x, w, bits=bits, ip=ip.name, **kw)
         from repro.kernels.fused.ops import fused_cnn_block
         return lambda: fused_cnn_block(x, w, ip=ip.name,
-                                       interpret=interpret, **kw)
+                                       **kw)
     if fam == "matmul":
         a = _synthetic(spec.shapes[0], spec.dtype, rng)
         b = _synthetic(spec.shapes[1], spec.dtype, rng)
         if lowered:
             from repro.quant.ops import quantized_matmul
-            return lambda: quantized_matmul(a, b, bits=bits, ip=ip.name,
-                                            interpret=interpret)
+            return lambda: quantized_matmul(a, b, bits=bits, ip=ip.name)
         from repro.kernels.matmul.ops import matmul
-        return lambda: matmul(a, b, ip=ip.name, interpret=interpret)
+        return lambda: matmul(a, b, ip=ip.name)
     raise ValueError(f"no calibration runner for family {fam!r} "
                      f"(site {spec.name!r})")
 
 
-def measure_planned_site(site, *, interpret: bool = True,
-                         warmup: int = 1, repeat: int = MEASURE_REPEAT,
+def measure_planned_site(site, *, warmup: int = 1,
+                         repeat: int = MEASURE_REPEAT,
                          seed: int = 0) -> float:
     """Measured us/call for one ``PlannedSite``: the planned member runs
     standalone on synthetic operands of the site's declared shapes, via
@@ -458,12 +453,12 @@ def measure_planned_site(site, *, interpret: bool = True,
                        "bits": site.precision_bits})
           if TRACER.enabled else NOOP_SPAN):
         return timeit_us(
-            _site_runner(site, interpret=interpret, seed=seed),
+            _site_runner(site, seed=seed),
             warmup=warmup, repeat=repeat)
 
 
 def collect_plan_samples(plans, table: Optional[CalibrationTable] = None, *,
-                         interpret: bool = True, warmup: int = 1,
+                         warmup: int = 1,
                          repeat: int = MEASURE_REPEAT,
                          seed: int = 0) -> CalibrationTable:
     """Measure every distinct (member, width, site) a set of plans chose
@@ -492,8 +487,7 @@ def collect_plan_samples(plans, table: Optional[CalibrationTable] = None, *,
             if dkey in seen:
                 continue
             seen.add(dkey)
-            us = measure_planned_site(site, interpret=interpret,
-                                      warmup=warmup, repeat=repeat,
+            us = measure_planned_site(site, warmup=warmup, repeat=repeat,
                                       seed=seed)
             table.record(site.ip.name, site.footprint, us,
                          family=site.spec.family,

@@ -36,6 +36,13 @@ LADDER_WIDTHS = (16, 8)
 WIDTH_DTYPES = {8: "int8", 16: "int16"}
 
 
+def kernel_dtype(dtype: str) -> str:
+    """The dtype a site's operands reach its kernel in: the 16-bit rung
+    is fake-quant, float32 arithmetic on the int16 grid
+    (``repro.quant.ops``); every other width runs as itself."""
+    return "float32" if dtype == WIDTH_DTYPES[16] else dtype
+
+
 @dataclasses.dataclass(frozen=True)
 class SiteSpec:
     """One op site of a network graph, declaratively.
@@ -140,6 +147,10 @@ class KernelIP:
     max_operand_bits: int = 32
     outputs_per_pass: int = 1
     supports_dtypes: Tuple[str, ...] = ("int8", "bfloat16", "float32")
+    # Operand dtypes Mosaic compiles this member for on the TPU; the
+    # interpreter runs every dtype.  The planner never offers a member at
+    # a dtype it cannot compile (``IPFamily.plan_site``).
+    compiled_dtypes: Tuple[str, ...] = ("int8", "bfloat16", "float32")
     tags: Tuple[str, ...] = ()
 
     def footprint(self, *shape_args, **shape_kwargs) -> Footprint:
@@ -152,6 +163,12 @@ class KernelIP:
 
     def feasible(self, budget: ResourceBudget, *shape_args, **shape_kwargs) -> bool:
         return self.footprint(*shape_args, **shape_kwargs).fits(budget)
+
+    def compiles(self, dtype: str) -> bool:
+        """Whether this member runs on the current backend at a site of
+        ``dtype`` (a ``SiteSpec.dtype``)."""
+        from repro.kernels import interpret
+        return interpret() or kernel_dtype(dtype) in self.compiled_dtypes
 
     def __call__(self, *args, **kwargs):
         return self.impl(*args, **kwargs)
@@ -198,7 +215,12 @@ class IPFamily:
             raise NotImplementedError(
                 f"family {self.name!r} has no site adapter registered; "
                 "it cannot be planned (see docs/adaptive_ips.md)")
-        return self.site_adapter(spec)
+        req = self.site_adapter(spec)
+        runnable = tuple(ip for ip in req.candidates
+                         if ip.compiles(spec.dtype))
+        if runnable == req.candidates:
+            return req
+        return dataclasses.replace(req, candidates=runnable)
 
     def register(self, ip: KernelIP) -> KernelIP:
         if ip.name in self.members:

@@ -39,7 +39,8 @@ CONV2D.register(KernelIP(
     name="conv2d.ip2_mxu", family="conv2d", impl=ip2_mxu.conv2d_ip2,
     footprint_fn=ip2_mxu.footprint, uses_mxu=True, max_operand_bits=32,
     outputs_per_pass=1, tags=("paper:Conv2",),
-    description="One MXU pass per tile; minimal vector logic."))
+    description="One MXU dot per tap and output row; minimal vector "
+                "logic."))
 CONV2D.register(KernelIP(
     name="conv2d.ip3_packed", family="conv2d", impl=ip3_packed.conv2d_ip3,
     footprint_fn=ip3_packed.footprint, uses_mxu=False, max_operand_bits=8,
@@ -50,16 +51,22 @@ CONV2D.register(KernelIP(
     name="conv2d.ip4_dual", family="conv2d", impl=ip4_dual.conv2d_ip4,
     footprint_fn=ip4_dual.footprint, uses_mxu=True, max_operand_bits=32,
     outputs_per_pass=2, tags=("paper:Conv4", "dual-stream"),
+    # Mosaic: "infer-vector-layout: unsupported shape cast" on int8
+    compiled_dtypes=("bfloat16", "float32"),
     description="Two parallel convolutions via dual MXU passes; full precision."))
 
 # --------------------------------------------------------------------------
 # pool2d family — the paper's future-work coverage: same resource split as
 # Conv1/Conv2 (logic-only windowed reduce vs im2col + one MXU pass).
+# Mosaic compiles both for 32-bit operands only: it has no strided load
+# of 8- or 16-bit data, no int8 max reduce and no int32 MXU dot, so on
+# the TPU a pool site stops at the 16-bit (fake-quant, f32) rung.
 # --------------------------------------------------------------------------
 POOL2D = IPFamily("pool2d", reference=pool2d_ref)
 POOL2D.register(KernelIP(
     name="pool2d.pool_vpu", family="pool2d", impl=pool_vpu_mod.pool2d_window,
     footprint_fn=pool_vpu_mod.footprint, uses_mxu=False,
+    compiled_dtypes=("float32",),
     tags=("analogue:Conv1", "windowed-reduce"),
     description="Unrolled strided-slice window reduce; pure VPU, "
                 "minimal VMEM."))
@@ -67,7 +74,7 @@ POOL2D.register(KernelIP(
     name="pool2d.pool_im2col", family="pool2d",
     impl=pool_im2col_mod.pool2d_im2col,
     footprint_fn=pool_im2col_mod.footprint, uses_mxu=True,
-    tags=("analogue:Conv2", "im2col"),
+    compiled_dtypes=("float32",), tags=("analogue:Conv2", "im2col"),
     description="Patch tensor in VMEM; avg collapses to one MXU pass, "
                 "max to one vectorized reduce."))
 
@@ -123,8 +130,9 @@ CNN_FUSED.register(KernelIP(
     name="cnn_fused.fused_mxu", family="cnn_fused",
     impl=fused_mod.fused_cnn_mxu, footprint_fn=fused_mod.footprint_mxu,
     uses_mxu=True, tags=("fused", "analogue:Conv2"),
-    description="Whole CNN block in one launch: im2col + one MXU pass, "
-                "pool + activation in register; single HBM write."))
+    description="Whole CNN block in one launch: one MXU dot per tap and "
+                "conv row, pool + activation in register; single HBM "
+                "write."))
 
 # --------------------------------------------------------------------------
 # matmul family — the LM-hot-path generalization.
@@ -259,7 +267,9 @@ def _activation_adapter(spec: SiteSpec) -> SiteRequest:
         candidates=tuple(cands),
         fp_args=(n_elems,),
         fp_kwargs=(("itemsize", jnp.dtype(spec.dtype).itemsize),
-                   ("kind", kind)),
+                   ("kind", kind),
+                   ("lanes", int(spec.shapes[0][-1]) if spec.shapes[0]
+                    else 1)),
         op_bits=0)
 
 

@@ -20,13 +20,24 @@ PEAK_BF16_FLOPS = 197e12          # bf16 MXU peak, FLOP/s
 PEAK_INT8_OPS = 394e12            # int8 MXU peak, OP/s (2x bf16)
 HBM_BYTES = 16 * 1024**3          # 16 GiB HBM
 HBM_BW = 819e9                    # bytes/s
-VMEM_BYTES = 128 * 1024 * 1024    # ~128 MiB vector memory
+# VMEM as the v5e compiler enforces it: a kernel may raise its scoped
+# limit (vmem_limit_bytes, default 16 MiB) up to the chip's 128 MiB; a
+# kernel holding 126 MiB of scratch compiles, one holding 128.03 MiB is
+# refused ("Used 128.03M of 128.00M vmem").
+VMEM_BYTES = 128 * 1024 * 1024
+# What the planner grants one kernel: the chip's VMEM less the headroom
+# kernels.vmem_limit adds on top of a footprint for the compiler's own
+# temporaries.
+KERNEL_VMEM_BYTES = VMEM_BYTES - 8 * 1024 * 1024
 ICI_BW_PER_LINK = 50e9            # bytes/s per ICI link (given)
 ICI_LINKS = 4                     # v5e 2D torus: 4 links/chip
 VPU_LANES = 8 * 128               # (8, 128) vector registers
 VPU_OPS_PER_CYCLE = 4 * VPU_LANES # 4 ALUs per lane pair (approx)
 CLOCK_HZ = 940e6                  # v5e core clock
 MXU_DIM = 128                     # systolic array is 128x128
+# bf16 MXU passes one f32 dot takes at Precision.HIGHEST (the 6-pass
+# bf16 decomposition); bf16 and int8 dots take one.
+F32_HIGHEST_PASSES = 6
 LANE = 128                        # last-dim tile
 SUBLANE = 8                       # second-to-last-dim tile (fp32)
 # Collective pricing unit: bytes one ICI link moves per core cycle —
@@ -34,6 +45,22 @@ SUBLANE = 8                       # second-to-last-dim tile (fp32)
 # (the FPGA analogy is the inter-board serial links of a multi-FPGA
 # deployment; a deployment with slower links overrides it per MeshSpec).
 ICI_BYTES_PER_CYCLE = ICI_BW_PER_LINK / CLOCK_HZ
+# The chip these constants describe, as JAX reports its device_kind.
+DEVICE_KIND = "TPU v5 lite"
+
+
+def check_device() -> None:
+    """Refuse to plan for an accelerator these constants do not describe.
+    On the CPU the kernels are interpreted and the model is a simulation
+    target, so any host passes."""
+    import jax
+    if jax.default_backend() == "cpu":
+        return
+    kinds = sorted({d.device_kind for d in jax.devices()})
+    if kinds != [DEVICE_KIND]:
+        raise RuntimeError(
+            f"the resource model describes {DEVICE_KIND!r} chips; this "
+            f"process sees {kinds}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,7 +130,7 @@ class ResourceBudget:
     (Conv3 is only legal up to 8-bit operands).
     """
 
-    vmem_bytes: int = VMEM_BYTES
+    vmem_bytes: int = KERNEL_VMEM_BYTES
     hbm_bytes: int = HBM_BYTES
     mxu_available: bool = True
     mxu_passes_budget: Optional[int] = None   # None = unlimited

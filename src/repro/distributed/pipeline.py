@@ -14,7 +14,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -86,6 +86,6 @@ def gpipe_forward(stage_fn: Callable, mesh: Mesh, *, axis: str = "pipe"):
             per_rank, mesh=mesh,
             in_specs=(param_specs, P()),
             out_specs=P(),
-            check_rep=False)(stage_params, x_micro)
+            check_vma=False)(stage_params, x_micro)
 
     return wrapper

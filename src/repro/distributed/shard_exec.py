@@ -35,7 +35,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.plan import NetworkPlan, PlannedSite
@@ -61,7 +61,7 @@ def _check_chain(plan: NetworkPlan) -> None:
 
 
 def _run_site(site: PlannedSite, x: jnp.ndarray, w: Optional[jnp.ndarray],
-              *, interpret: bool, reduce_axis: Optional[str] = None,
+              *, reduce_axis: Optional[str] = None,
               use_ring: bool = False) -> jnp.ndarray:
     """One site through its planned member's ops entry — shared by the
     replicated and the per-device walks (the per-device walk passes
@@ -69,31 +69,29 @@ def _run_site(site: PlannedSite, x: jnp.ndarray, w: Optional[jnp.ndarray],
     spec = site.spec
     if spec.family == "conv2d":
         from repro.kernels.conv2d.ops import conv2d
-        return conv2d(x, w, ip=site.ip.name, interpret=interpret,
-                      reduce_axis=reduce_axis,
+        return conv2d(x, w, ip=site.ip.name, reduce_axis=reduce_axis,
                       reduce="ring" if use_ring else "psum")
     if spec.family == "pool2d":
         from repro.kernels.pool2d.ops import pool2d
         return pool2d(x, window=spec.knob("window", (2, 2)),
                       stride=spec.knob("stride"),
                       mode=spec.knob("mode", "max"),
-                      ip=site.ip.name, interpret=interpret)
+                      ip=site.ip.name)
     if spec.family == "activation":
         from repro.kernels.activation.ops import activation
         return activation(x, kind=spec.knob("kind", "relu"),
-                          ip=site.ip.name, interpret=interpret)
+                          ip=site.ip.name)
     # cnn_fused (gated by _check_chain)
     from repro.kernels.fused.ops import fused_cnn_block
     return fused_cnn_block(
         x, w, pool_window=spec.knob("window", (2, 2)),
         pool_stride=spec.knob("stride"), pool_mode=spec.knob("mode", "max"),
-        activation=spec.knob("kind", "relu"), ip=site.ip.name,
-        interpret=interpret)
+        activation=spec.knob("kind", "relu"), ip=site.ip.name)
 
 
 def apply_plan_replicated(plan: NetworkPlan, x: jnp.ndarray,
-                          weights: Optional[Dict[str, jnp.ndarray]] = None,
-                          *, interpret: bool = True) -> jnp.ndarray:
+                          weights: Optional[Dict[str, jnp.ndarray]] = None
+                          ) -> jnp.ndarray:
     """The single-device reference walk: every site's planned member on
     the full tensors, no mesh.  ``weights`` maps conv/fused site name ->
     its weight tensor."""
@@ -101,8 +99,7 @@ def apply_plan_replicated(plan: NetworkPlan, x: jnp.ndarray,
     weights = weights or {}
     cur = x
     for site in plan.sites:
-        cur = _run_site(site, cur, weights.get(site.spec.name),
-                        interpret=interpret)
+        cur = _run_site(site, cur, weights.get(site.spec.name))
     return cur
 
 
@@ -131,7 +128,7 @@ def _relay(x: jnp.ndarray, have, want, axis: str, index) -> jnp.ndarray:
 
 def apply_plan_sharded(plan: NetworkPlan, x: jnp.ndarray,
                        weights: Optional[Dict[str, jnp.ndarray]] = None,
-                       *, interpret: bool = True, use_ring: bool = False,
+                       *, use_ring: bool = False,
                        devices=None) -> jnp.ndarray:
     """Execute ``plan`` under its mesh: one ``shard_map`` over the whole
     chain, layouts threaded exactly as the planner priced them.
@@ -146,7 +143,7 @@ def apply_plan_sharded(plan: NetworkPlan, x: jnp.ndarray,
     _check_chain(plan)
     if (plan.mesh is None or plan.mesh.devices <= 1
             or not plan.sharded_sites()):
-        return apply_plan_replicated(plan, x, weights, interpret=interpret)
+        return apply_plan_replicated(plan, x, weights)
     weights = weights or {}
     d = plan.mesh.devices
     axis = plan.mesh.axis
@@ -175,14 +172,14 @@ def apply_plan_sharded(plan: NetworkPlan, x: jnp.ndarray,
                 w = _slice_block(w, 2, gsite.shard_degree, index)
                 reduce_axis = axis
             run = dsite if gsite.sharded else gsite
-            cur = _run_site(run, cur, w, interpret=interpret,
-                            reduce_axis=reduce_axis, use_ring=use_ring)
+            cur = _run_site(run, cur, w, reduce_axis=reduce_axis,
+                            use_ring=use_ring)
             have = output_layout(gsite.spec, gsite.shard_axis,
                                  gsite.shard_degree)
         return _relay(cur, have, FULL, axis, index)
 
     fn = shard_map(device_fn, mesh=mesh, in_specs=(P(), P()),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     with (TRACER.span("shard_exec.apply", "collective",
                       {"devices": d, "axis": axis,
                        "comm_cycles": sum(s.footprint.comm_cycles

@@ -26,7 +26,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.launch.mesh import dp_axes, mesh_axis_sizes
+from repro.launch.mesh import auto_axes, dp_axes, mesh_axis_sizes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -271,5 +271,8 @@ def cache_pspecs(cfg: ModelConfig, mesh: Mesh, cache_tree,
 
 
 def to_shardings(mesh: Mesh, spec_tree):
+    """NamedShardings over ``mesh``'s devices with ``Auto`` axes, whatever
+    axis types the caller's mesh has (see ``launch/mesh.py``)."""
+    mesh = auto_axes(mesh)
     return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                         is_leaf=lambda x: isinstance(x, P))

@@ -25,8 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.resources import (Footprint, cost_cycles, hbm_cycles,
+from repro.core.resources import (LANE, Footprint, cost_cycles,
                                   vpu_op_cycles)
+from repro.kernels import pallas_call, tile_bytes
 from repro.kernels.activation.ref import activation_ref
 
 TABLE_SIZE = 256
@@ -45,45 +46,68 @@ def build_table(kind: str) -> jnp.ndarray:
 
 
 def _kernel(x_ref, t_ref, o_ref, *, r, out_dtype):
+    # x_ref, o_ref: (bm, LANE); t_ref: (TABLE_SIZE // LANE, LANE).  The
+    # lookup is one lane gather per 128-entry half of the table (the
+    # TPU gathers along lanes within a vreg row), then a select.
     x = x_ref[...].astype(jnp.float32)
     scale = (TABLE_SIZE - 1) / (2.0 * r)
     q = jnp.clip(jnp.round((x + r) * scale), 0, TABLE_SIZE - 1)
-    o_ref[...] = jnp.take(t_ref[...], q.astype(jnp.int32)).astype(out_dtype)
+    q = q.astype(jnp.int32)
+    lo = q % LANE
+    y = None
+    for half in range(TABLE_SIZE // LANE):
+        row = jnp.broadcast_to(t_ref[half:half + 1, :], x.shape)
+        g = jnp.take_along_axis(row, lo, axis=1, mode="promise_in_bounds")
+        y = g if y is None else jnp.where(q >= half * LANE, g, y)
+    o_ref[...] = y.astype(out_dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("kind", "block_rows", "interpret"))
+@functools.partial(jax.jit, static_argnames=("kind", "block_rows"))
 def activation_lut(x: jnp.ndarray, *, kind: str = "tanh",
-                   block_rows: int = 256,
-                   interpret: bool = True) -> jnp.ndarray:
+                   block_rows: int = 256) -> jnp.ndarray:
     if kind not in RANGES:
         raise ValueError(
             f"LUT activation supports saturating kinds {SUPPORTED_KINDS}; "
             f"{kind!r} is unbounded — use the exact IP")
     out_dtype = (x.dtype if jnp.issubdtype(x.dtype, jnp.floating)
                  else jnp.float32)
-    table = build_table(kind)
-    shape = x.shape
-    k = shape[-1] if x.ndim >= 1 and shape else 1
-    x2 = x.reshape(-1, k) if x.ndim != 2 else x
-    m = x2.shape[0]
+    table = build_table(kind).reshape(TABLE_SIZE // LANE, LANE)
+    # elementwise: any layout will do, so view x as full 128-lane rows
+    n = x.size
+    m = -(-n // LANE)
     bm = min(block_rows, m)
-    y2 = pl.pallas_call(
+    flat = x.reshape(-1)
+    if m * LANE != n:
+        flat = jnp.pad(flat, (0, m * LANE - n))
+    y2 = pallas_call(
         functools.partial(_kernel, r=RANGES[kind], out_dtype=out_dtype),
         grid=(pl.cdiv(m, bm),),
-        in_specs=[pl.BlockSpec((bm, k), lambda i: (i, 0)),
-                  pl.BlockSpec((TABLE_SIZE,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((bm, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, k), out_dtype),
-        interpret=interpret,
-    )(x2, table)
-    return y2.reshape(shape)
+        vmem_bytes=_vmem(bm, x.dtype.itemsize),
+        in_specs=[pl.BlockSpec((bm, LANE), lambda i: (i, 0)),
+                  pl.BlockSpec(table.shape, lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((bm, LANE), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, LANE), out_dtype),
+    )(flat.reshape(m, LANE), table)
+    return y2.reshape(-1)[:n].reshape(x.shape)
 
 
-def footprint(n_elems, *, itemsize=4, kind="tanh",
-              block_rows: int = 256, lanes: int = 128) -> Footprint:
-    block = min(block_rows * lanes, n_elems)
-    vmem = block * itemsize + block * 4 + TABLE_SIZE * 4
+def _vmem(bm, itemsize) -> int:
+    """Double-buffered (bm, 128) in and f32 out tiles, the table, and
+    the body's f32 index, broadcast table row and gathered values."""
+    return (2 * tile_bytes((bm, LANE), itemsize)
+            + 2 * tile_bytes((bm, LANE), 4)
+            + 2 * tile_bytes((TABLE_SIZE // LANE, LANE), 4)
+            + 4 * tile_bytes((bm, LANE), 4))
+
+
+def footprint(n_elems, *, itemsize=4, kind="tanh", block_rows: int = 256,
+              lanes: int = LANE) -> Footprint:
+    """``lanes`` (the input's trailing dim, which the planner passes to
+    every activation member) does not matter here: the kernel views any
+    input as full 128-lane rows."""
+    del lanes
+    bm = max(1, min(block_rows, -(-n_elems // LANE)))
+    vmem = _vmem(bm, itemsize)
     # Deployment story: operands stream as 1-byte fixed-point codes
     # (quantize at the producer, dequantize at the consumer) plus the table.
     hbm = n_elems * 2 + TABLE_SIZE * 4

@@ -21,8 +21,8 @@ _MEMBERS = {"act_vpu": activation_exact, "act_lut": activation_lut}
 
 def activation(x: jnp.ndarray, *, kind: str = "relu",
                ip: Optional[str] = None,
-               budget: Optional[ResourceBudget] = None, ladder=(),
-               interpret: bool = True) -> jnp.ndarray:
+               budget: Optional[ResourceBudget] = None,
+               ladder=()) -> jnp.ndarray:
     """Elementwise activation through a selected IP (Act1/Act2)."""
     if ip is None:
         from repro.core.ip import SiteSpec
@@ -34,11 +34,10 @@ def activation(x: jnp.ndarray, *, kind: str = "relu",
             from repro.quant.ops import quantized_activation
             return quantized_activation(x, kind=kind,
                                         bits=planned.precision_bits,
-                                        ip=planned.ip.name,
-                                        interpret=interpret)
+                                        ip=planned.ip.name)
         ip = planned.ip.name
     ip = ip.split(".")[-1]
     if ip not in _MEMBERS:
         raise KeyError(
             f"{ip!r} is not an activation IP (have {sorted(_MEMBERS)})")
-    return _MEMBERS[ip](x, kind=kind, interpret=interpret)
+    return _MEMBERS[ip](x, kind=kind)

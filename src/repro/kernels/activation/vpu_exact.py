@@ -17,8 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.resources import (Footprint, cost_cycles, hbm_cycles,
-                                  vpu_op_cycles)
+from repro.core.resources import Footprint, cost_cycles, vpu_op_cycles
+from repro.kernels import pallas_call, tile_bytes
 from repro.kernels.activation.ref import _FNS, KINDS
 
 # Approximate VPU scalar-op cost per element (mul/add/cmp units).
@@ -30,11 +30,9 @@ def _kernel(x_ref, o_ref, *, kind, out_dtype):
     o_ref[...] = y.astype(out_dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("kind", "block_rows", "interpret"))
+@functools.partial(jax.jit, static_argnames=("kind", "block_rows"))
 def activation_exact(x: jnp.ndarray, *, kind: str = "relu",
-                     block_rows: int = 256,
-                     interpret: bool = True) -> jnp.ndarray:
+                     block_rows: int = 256) -> jnp.ndarray:
     if kind not in KINDS:
         raise ValueError(f"unknown activation {kind!r}; have {KINDS}")
     out_dtype = (x.dtype if jnp.issubdtype(x.dtype, jnp.floating)
@@ -44,21 +42,30 @@ def activation_exact(x: jnp.ndarray, *, kind: str = "relu",
     x2 = x.reshape(-1, k) if x.ndim != 2 else x
     m = x2.shape[0]
     bm = min(block_rows, m)
-    y2 = pl.pallas_call(
+    y2 = pallas_call(
         functools.partial(_kernel, kind=kind, out_dtype=out_dtype),
         grid=(pl.cdiv(m, bm),),
+        vmem_bytes=_vmem(bm, k, x.dtype.itemsize,
+                         jnp.dtype(out_dtype).itemsize),
         in_specs=[pl.BlockSpec((bm, k), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, k), out_dtype),
-        interpret=interpret,
     )(x2)
     return y2.reshape(shape)
 
 
-def footprint(n_elems, *, itemsize=4, kind="relu",
-              block_rows: int = 256, lanes: int = 128) -> Footprint:
-    block = min(block_rows * lanes, n_elems)
-    vmem = block * itemsize + block * 4            # in tile + f32 out tile
+def _vmem(bm, k, itemsize, out_item) -> int:
+    """Double-buffered (bm, K) in and out tiles plus the f32 body value."""
+    return (2 * tile_bytes((bm, k), itemsize)
+            + 2 * tile_bytes((bm, k), out_item)
+            + tile_bytes((bm, k), 4))
+
+
+def footprint(n_elems, *, itemsize=4, kind="relu", block_rows: int = 256,
+              lanes: int = 128) -> Footprint:
+    """``lanes`` is the trailing (channel) dim the kernel tiles over."""
+    bm = max(1, min(block_rows, n_elems // max(lanes, 1)))
+    vmem = _vmem(bm, min(lanes, n_elems), itemsize, max(itemsize, 4))
     hbm = n_elems * (itemsize + itemsize)          # stream in + out
     vpu = n_elems * OP_COST.get(kind, 8)
     return Footprint(vmem_bytes=vmem, hbm_bytes=hbm, mxu_passes=0,

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from repro.core.resources import Footprint, hbm_cycles
 
 _NEG_INF = -1e30
@@ -54,8 +55,8 @@ def _decode_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bk", "interpret"))
-def flash_decode(q, k, v, *, bk: int = 1024, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("bk",))
+def flash_decode(q, k, v, *, bk: int = 1024):
     """q: (B, Hq, 1, D); k/v: (B, Hkv, Skv, D) -> (B, Hq, 1, D)."""
     b, hq, sq, d = q.shape
     assert sq == 1, "flash_decode is the single-token member"
@@ -87,7 +88,7 @@ def flash_decode(q, k, v, *, bk: int = 1024, interpret: bool = True):
         scratch_shapes=[pltpu.VMEM((group,), jnp.float32),
                         pltpu.VMEM((group,), jnp.float32),
                         pltpu.VMEM((group, d), jnp.float32)],
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(qr, kr, vr)
     return out.reshape(b, hq, 1, d)
 

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from repro.core.resources import (Footprint, cost_cycles, hbm_cycles,
                                   mxu_pass_cycles)
 
@@ -73,9 +74,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "bq", "bk"))
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,
-                    bk: int = 512, interpret: bool = True):
+                    bk: int = 512):
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     group = hq // hkv
@@ -114,7 +115,7 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,
         scratch_shapes=[pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(qr, kr, vr)
     return out.reshape(b, hq, sqp, d)[:, :, :sq, :]
 

@@ -17,8 +17,7 @@ from repro.kernels.attention.ref import attention_ref
 
 
 def attention(q, k, v, *, causal: bool = True, ip: Optional[str] = None,
-              budget: Optional[ResourceBudget] = None,
-              interpret: bool = True):
+              budget: Optional[ResourceBudget] = None):
     if ip is None:
         from repro.core.ip import SiteSpec
         from repro.core.plan import plan_single
@@ -27,9 +26,9 @@ def attention(q, k, v, *, causal: bool = True, ip: Optional[str] = None,
         ip = plan_single(spec, budget).ip.name
     ip = ip.split(".")[-1]
     if ip == "attn_flash":
-        return flash_attention(q, k, v, causal=causal, interpret=interpret)
+        return flash_attention(q, k, v, causal=causal)
     if ip == "attn_decode":
-        return flash_decode(q, k, v, interpret=interpret)
+        return flash_decode(q, k, v)
     if ip == "attn_naive":
         return attention_ref(q, k, v, causal=causal)
     raise KeyError(f"unknown attention IP {ip!r}")

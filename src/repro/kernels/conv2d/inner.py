@@ -1,58 +1,202 @@
-"""Shared conv inner-loop bodies — the single source of the per-tile
-convolution math.
+"""Shared row-blocked conv machinery — the single source of the per-row
+convolution math and of the grid every conv-shaped member walks.
 
 The standalone members (``ip1_vpu``, ``ip2_mxu``) and the fused
 conv->pool->act members (``kernels/fused/cnn_block.py``) compute the
-same accumulator tile; keeping the loop bodies here means a fused kernel
-cannot drift numerically from the standalone IP it absorbs — the fusion
-tests assert bitwise equality in float32, and that only holds because
-both paths run literally this code.
+same conv rows; keeping the row body here means a fused kernel cannot
+drift numerically from the standalone IP it absorbs — the fusion tests
+assert bitwise equality in float32, and that only holds because both
+paths run literally this code.
 
-Both helpers take the *already-loaded* VMEM views (one image plane, one
-weight tile) and return the (Ho, Wo, bc) accumulator; callers own the
-Ref loads/stores and the grid.
+Tiling (all conv-shaped members): grid ``(Cout tiles, batch, row
+blocks)``.  A grid step holds one window of input rows — its block of
+output rows plus the ``kh - 1`` halo rows below it, fetched with
+element-indexed (overlapping) blocks — and one weight tile, and loops
+over its output rows.  The working set is bounded by the row block, not
+by the image, so the same member compiles at 32 px and at 224 px.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from repro.core.resources import F32_HIGHEST_PASSES, mxu_pass_cycles
+from repro.kernels import pallas_call, round_up, tile_bytes
+
+# Output rows one grid step computes (pooled rows for the fused members):
+# the bound on a step's working set that the footprints price.
+BLOCK_ROWS = 8
 
 
-def accumulate_vpu(x, w_ref, *, ho: int, wo: int, kh: int, kw: int,
-                   acc_dtype):
-    """Conv1-style logic-only accumulation: unrolled shifted
-    multiply-accumulate over the taps — pure VPU, no dot op.
+def acc_dtype_for(dtype):
+    """Integer operands accumulate exactly in int32, floats in f32."""
+    return jnp.int32 if jnp.issubdtype(dtype, jnp.integer) else jnp.float32
 
-    ``x``: (H, W, Cin) plane already cast to ``acc_dtype``;
-    ``w_ref``: (kh, kw, Cin, bc) weight Ref.  Returns (Ho, Wo, bc).
+
+def _dot_precision(dtype):
+    # f32 operands take full-precision MXU passes, not the chip's default
+    # single bf16 pass; Mosaic refuses HIGHEST for narrower operands
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def conv_row(x_ref, w_ref, row, *, wpad: int, kh: int, kw: int, style: str,
+             acc_dtype):
+    """One conv output row ``(wpad, bc)`` from input rows
+    ``row .. row + kh - 1`` of the resident window ``x_ref``
+    ``(1, rows, wpad + kw - 1, Cin)``.
+
+    ``style="mxu"`` (Conv2): each tap is one MXU pass over the row.
+    ``style="vpu"`` (Conv1): each tap is a broadcast multiply + reduce
+    over Cin — pure VPU work, no dot op.
     """
-    acc = jnp.zeros((ho, wo, w_ref.shape[-1]), dtype=acc_dtype)
+    acc = None
     for i in range(kh):
         for j in range(kw):
-            window = x[i:i + ho, j:j + wo, :]           # (Ho, Wo, Cin)
-            tap = w_ref[i, j].astype(acc_dtype)         # (Cin, bc)
-            # Elementwise broadcast-multiply + reduce over Cin — the
-            # reduce is a chain of adds, not a dot: keep it explicit so
-            # Mosaic lowers it to VPU ops.
-            prod = window[..., :, None] * tap[None, None, :, :]
-            acc = acc + jnp.sum(prod, axis=2)
+            xs = x_ref[0, row + i, pl.ds(j, wpad), :]          # (wpad, Cin)
+            tap = w_ref[i, j]                                   # (Cin, bc)
+            if style == "mxu":
+                part = jnp.dot(xs, tap, preferred_element_type=acc_dtype,
+                               precision=_dot_precision(xs.dtype))
+            else:
+                part = jnp.sum(xs.astype(acc_dtype)[:, :, None]
+                               * tap.astype(acc_dtype)[None, :, :], axis=1)
+            acc = part if acc is None else acc + part
     return acc
 
 
-def accumulate_mxu(x, w_ref, *, ho: int, wo: int, kh: int, kw: int,
-                   acc_dtype):
-    """Conv2-style accumulation: im2col built in VMEM from shifted
-    slices, the whole tap reduction collapsing into ONE MXU pass.
+def row_window_spec(rows_in: int, win: int, cin: int, rows_per_block: int):
+    """Element-indexed block over the padded input: block ``r`` of batch
+    ``b`` starts at row ``r * rows_per_block`` and spans ``rows_in``
+    rows (the block's rows plus its halo)."""
+    return pl.BlockSpec(
+        (pl.Element(1), pl.Element(rows_in), pl.Element(win), pl.Element(cin)),
+        lambda c, b, r: (b, r * rows_per_block, 0, 0))
 
-    ``x``: (H, W, Cin) plane in the operand dtype; ``w_ref``:
-    (kh, kw, Cin, bc) weight Ref.  Returns (Ho, Wo, bc).
-    """
-    cin = x.shape[-1]
-    cols = []
-    for i in range(kh):
-        for j in range(kw):
-            cols.append(x[i:i + ho, j:j + wo, :])
-    patches = jnp.concatenate(cols, axis=-1).reshape(ho * wo, kh * kw * cin)
-    wmat = w_ref[...].reshape(kh * kw * cin, -1)        # (kh*kw*Cin, bc)
-    # THE single MXU pass:
-    acc = jnp.dot(patches, wmat, preferred_element_type=acc_dtype)
-    return acc.reshape(ho, wo, -1)
+
+def pad_input(x, rows: int, cols: int):
+    """Zero-pad (N, H, W, C) at the bottom/right to at least ``rows`` x
+    ``cols``."""
+    _, h, w, _ = x.shape
+    if rows <= h and cols <= w:
+        return x
+    return jnp.pad(x, ((0, 0), (0, max(rows - h, 0)), (0, max(cols - w, 0)),
+                       (0, 0)))
+
+
+def row_geometry(ho: int, wo: int, kh: int, kw: int):
+    """(rows per block, row blocks, computed row width, input window
+    width, input rows per block) of a conv walking ``ho`` x ``wo``."""
+    tr = max(1, min(BLOCK_ROWS, ho))
+    wpad = round_up(wo, 8)
+    return tr, -(-ho // tr), wpad, wpad + kw - 1, tr + kh - 1
+
+
+def conv_body_vmem(wpad: int, cin: int, bc: int, itemsize: int,
+                   style: str, taps: int) -> int:
+    """VMEM the conv row body holds live: the f32/int32 accumulator, the
+    loaded row slice and the tap, plus for the VPU style one
+    ``(wpad, Cin, bc)`` broadcast product per tap — the compiler
+    schedules the unrolled taps' products to be live together (at the
+    VGG stages it asks for 7-9 of them)."""
+    live = (2 * tile_bytes((wpad, bc), 4) + tile_bytes((wpad, cin), itemsize)
+            + tile_bytes((cin, bc), itemsize))
+    if style == "vpu":
+        live += taps * tile_bytes((wpad, cin, bc), 4)
+    return live
+
+
+def conv_mxu_cycles(n: int, rows: int, wo: int, cin: int, kh: int,
+                    kw: int, cout: int, *, itemsize: int,
+                    block_cout: int) -> float:
+    """MXU cycles of the MXU-style row body over ``rows`` conv output
+    rows per image: each row of each Cout tile issues ``kh * kw``
+    ``(wpad, Cin) x (Cin, bc)`` dots, and an f32 dot at
+    ``Precision.HIGHEST`` is ``F32_HIGHEST_PASSES`` bf16 passes."""
+    bc = min(block_cout, cout)
+    dots = n * rows * kh * kw * -(-cout // bc)
+    passes = F32_HIGHEST_PASSES if itemsize == 4 else 1
+    return dots * passes * mxu_pass_cycles(round_up(wo, 8), cin, bc)
+
+
+def conv_block_vmem(rows_in: int, win: int, cin: int, kh: int, kw: int,
+                    bc: int, itemsize: int) -> int:
+    """Double-buffered input window + weight tile of one grid step."""
+    return 2 * (tile_bytes((rows_in, win, cin), itemsize)
+                + tile_bytes((kh * kw, cin, bc), itemsize))
+
+
+def for_rows(n: int, body) -> None:
+    """Run ``body(row)`` for ``row`` in ``0 .. n - 1`` as a loop (not
+    unrolled: compile time stays flat in the block height)."""
+    def step(i, carry):
+        body(i)
+        return carry
+    jax.lax.fori_loop(0, n, step, 0)
+
+
+def conv_vmem(h: int, w: int, cin: int, kh: int, kw: int, cout: int, *,
+              itemsize: int, style: str, block_cout: int) -> int:
+    """VMEM of one standalone conv grid step: double-buffered input
+    window, weight tile and output block, plus the row body."""
+    ho, wo = h - kh + 1, w - kw + 1
+    bc = min(block_cout, cout)
+    tr, _, wpad, win, rows_in = row_geometry(ho, wo, kh, kw)
+    return (conv_block_vmem(rows_in, win, cin, kh, kw, bc, itemsize)
+            + 2 * tile_bytes((tr, wo, bc), 4)
+            + conv_body_vmem(wpad, cin, bc, itemsize, style, kh * kw))
+
+
+def _conv_kernel(x_ref, w_ref, o_ref, *, kh, kw, style, acc_dtype):
+    # x_ref: (1, rows_in, win, Cin); w_ref: (kh, kw, Cin, bc);
+    # o_ref: (1, tr, Wo, bc)
+    wo = o_ref.shape[2]
+    wpad = round_up(wo, 8)
+
+    def row(r):
+        acc = conv_row(x_ref, w_ref, r, wpad=wpad, kh=kh, kw=kw,
+                       style=style, acc_dtype=acc_dtype)
+        o_ref[0, r] = acc[:wo]
+
+    for_rows(o_ref.shape[1], row)
+
+
+def conv_call(x, w, *, style: str, block_cout: int):
+    """Row-blocked VALID stride-1 conv through one Pallas launch."""
+    n, h, w_, cin = x.shape
+    kh, kw, _, cout = w.shape
+    ho, wo = h - kh + 1, w_ - kw + 1
+    acc_dtype = acc_dtype_for(x.dtype)
+    bc = min(block_cout, cout)
+    tr, n_rb, _, win, rows_in = row_geometry(ho, wo, kh, kw)
+    xp = pad_input(x, n_rb * tr + kh - 1, win)
+    out = pallas_call(
+        functools.partial(_conv_kernel, kh=kh, kw=kw, style=style,
+                          acc_dtype=acc_dtype),
+        grid=(pl.cdiv(cout, bc), n, n_rb),
+        vmem_bytes=conv_vmem(h, w_, cin, kh, kw, cout,
+                             itemsize=x.dtype.itemsize, style=style,
+                             block_cout=block_cout),
+        in_specs=[row_window_spec(rows_in, win, cin, tr),
+                  pl.BlockSpec((kh, kw, cin, bc),
+                               lambda c, b, r: (0, 0, 0, c))],
+        out_specs=pl.BlockSpec((1, tr, wo, bc), lambda c, b, r: (b, r, 0, c)),
+        out_shape=jax.ShapeDtypeStruct((n, n_rb * tr, wo, cout), acc_dtype),
+    )(xp, w)
+    return out if n_rb * tr == ho else out[:, :ho]
+
+
+def conv_hbm(n: int, h: int, w: int, cin: int, kh: int, kw: int, cout: int,
+             *, itemsize: int, block_cout: int) -> int:
+    """HBM bytes one standalone conv moves: every Cout tile re-reads the
+    input windows (halo rows included), the weights are read once, the
+    int32/f32 output is written once."""
+    ho, wo = h - kh + 1, w - kw + 1
+    bc = min(block_cout, cout)
+    _, n_rb, _, win, rows_in = row_geometry(ho, wo, kh, kw)
+    tiles = -(-cout // bc)
+    return (tiles * n * n_rb * rows_in * win * cin * itemsize
+            + kh * kw * cin * cout * itemsize
+            + n * ho * wo * cout * 4)

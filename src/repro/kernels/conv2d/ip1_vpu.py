@@ -1,15 +1,15 @@
 """Conv1 — logic-only convolution (paper: 0 DSP, high LUT/CLB usage).
 
 TPU-native reading: the kernel body issues **no dot op** — every
-multiply-accumulate runs on the VPU as an elementwise shifted
-multiply-add over the image plane.  High vector-op count, zero MXU
-passes.  This is the variant the selector picks when the MXU is
+multiply-accumulate runs on the VPU as a broadcast multiply + reduce
+over the input channels of each output row.  High vector-op count, zero
+MXU passes.  This is the variant the selector picks when the MXU is
 unavailable / saturated (budget.mxu_available=False), exactly the
 paper's "suitable for FPGAs with limited DSPs".
 
-Tiling: grid over (batch, Cout tiles).  Each grid step holds one image
-plane (H, W, Cin), one weight tile (KH, KW, Cin, bc) and one output
-plane (Ho, Wo, bc) in VMEM.
+Tiling: the shared row-blocked grid (``kernels/conv2d/inner.py``).  The
+row body holds a ``(W, Cin, bc)`` broadcast product, which the
+footprint counts.
 """
 from __future__ import annotations
 
@@ -17,53 +17,24 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.core.resources import (Footprint, cost_cycles, hbm_cycles,
-                                  vpu_op_cycles)
-from repro.kernels.conv2d.inner import accumulate_vpu
+from repro.core.resources import Footprint, cost_cycles, vpu_op_cycles
+from repro.kernels.conv2d.inner import conv_call, conv_hbm, conv_vmem
 
 
-def _kernel(x_ref, w_ref, o_ref, *, kh: int, kw: int, acc_dtype):
-    # x_ref: (1, H, W, Cin); w_ref: (kh, kw, Cin, bc); o_ref: (1, Ho, Wo, bc)
-    x = x_ref[0].astype(acc_dtype)                      # (H, W, Cin)
-    o_ref[0] = accumulate_vpu(x, w_ref, ho=o_ref.shape[1], wo=o_ref.shape[2],
-                              kh=kh, kw=kw, acc_dtype=acc_dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("block_cout", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_cout",))
 def conv2d_ip1(x: jnp.ndarray, w: jnp.ndarray, *,
-               block_cout: int = 128, interpret: bool = True) -> jnp.ndarray:
-    n, h, w_, cin = x.shape
-    kh, kw, _, cout = w.shape
-    ho, wo = h - kh + 1, w_ - kw + 1
-    acc_dtype = (jnp.int32 if jnp.issubdtype(x.dtype, jnp.integer)
-                 else jnp.float32)
-    bc = min(block_cout, cout)
-    grid = (n, pl.cdiv(cout, bc))
-    return pl.pallas_call(
-        functools.partial(_kernel, kh=kh, kw=kw, acc_dtype=acc_dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, h, w_, cin), lambda b, c: (b, 0, 0, 0)),
-            pl.BlockSpec((kh, kw, cin, bc), lambda b, c: (0, 0, 0, c)),
-        ],
-        out_specs=pl.BlockSpec((1, ho, wo, bc), lambda b, c: (b, 0, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((n, ho, wo, cout), acc_dtype),
-        interpret=interpret,
-    )(x, w)
+               block_cout: int = 128) -> jnp.ndarray:
+    return conv_call(x, w, style="vpu", block_cout=block_cout)
 
 
 def footprint(n, h, w, cin, kh, kw, cout, *, itemsize=1,
               block_cout: int = 128) -> Footprint:
     ho, wo = h - kh + 1, w - kw + 1
-    bc = min(block_cout, cout)
-    vmem = (h * w * cin * itemsize            # x plane
-            + kh * kw * cin * bc * itemsize   # weight tile
-            + ho * wo * bc * 4)               # int32/f32 accumulator
-    hbm = (n * h * w * cin * itemsize
-           + kh * kw * cin * cout * itemsize
-           + n * ho * wo * cout * 4)
+    vmem = conv_vmem(h, w, cin, kh, kw, cout, itemsize=itemsize,
+                     style="vpu", block_cout=block_cout)
+    hbm = conv_hbm(n, h, w, cin, kh, kw, cout, itemsize=itemsize,
+                   block_cout=block_cout)
     vpu = n * ho * wo * cout * kh * kw * cin * 2   # mul+add per tap
     return Footprint(vmem_bytes=vmem, hbm_bytes=hbm, mxu_passes=0,
                      vpu_ops=vpu,

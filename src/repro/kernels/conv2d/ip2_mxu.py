@@ -1,10 +1,12 @@
-"""Conv2 — single-MXU convolution (paper: 1 DSP, low logic).
+"""Conv2 — MXU convolution (paper: 1 DSP, low logic).
 
-TPU-native reading: im2col is built inside VMEM from shifted slices and
-the whole tap reduction collapses into **one MXU pass** per grid step
-(`jnp.dot` with int32/f32 accumulation).  Minimal vector logic — the
-paper's "reduces the use of logic; ideal for FPGAs with DSP
+TPU-native reading: each output row is ``kh * kw`` MXU passes, one per
+tap — a shifted row slice of the resident input window times the tap's
+``(Cin, bc)`` weight matrix, accumulated in int32/f32.  Minimal vector
+logic — the paper's "reduces the use of logic; ideal for FPGAs with DSP
 availability and limited logic resources".
+
+Tiling: the shared row-blocked grid (``kernels/conv2d/inner.py``).
 """
 from __future__ import annotations
 
@@ -12,41 +14,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.core.resources import (Footprint, cost_cycles, hbm_cycles,
-                                  mxu_pass_cycles)
-from repro.kernels.conv2d.inner import accumulate_mxu
-
-
-def _kernel(x_ref, w_ref, o_ref, *, kh: int, kw: int, acc_dtype):
-    # x_ref: (1, H, W, Cin); w_ref: (kh, kw, Cin, bc); o_ref: (1, Ho, Wo, bc)
-    o_ref[0] = accumulate_mxu(x_ref[0], w_ref, ho=o_ref.shape[1],
-                              wo=o_ref.shape[2], kh=kh, kw=kw,
-                              acc_dtype=acc_dtype)
+from repro.core.resources import Footprint, cost_cycles
+from repro.kernels.conv2d.inner import (conv_call, conv_hbm,
+                                        conv_mxu_cycles, conv_vmem)
 
 
-@functools.partial(jax.jit, static_argnames=("block_cout", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_cout",))
 def conv2d_ip2(x: jnp.ndarray, w: jnp.ndarray, *,
-               block_cout: int = 128, interpret: bool = True) -> jnp.ndarray:
-    n, h, w_, cin = x.shape
-    kh, kw, _, cout = w.shape
-    ho, wo = h - kh + 1, w_ - kw + 1
-    acc_dtype = (jnp.int32 if jnp.issubdtype(x.dtype, jnp.integer)
-                 else jnp.float32)
-    bc = min(block_cout, cout)
-    grid = (n, pl.cdiv(cout, bc))
-    return pl.pallas_call(
-        functools.partial(_kernel, kh=kh, kw=kw, acc_dtype=acc_dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, h, w_, cin), lambda b, c: (b, 0, 0, 0)),
-            pl.BlockSpec((kh, kw, cin, bc), lambda b, c: (0, 0, 0, c)),
-        ],
-        out_specs=pl.BlockSpec((1, ho, wo, bc), lambda b, c: (b, 0, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((n, ho, wo, cout), acc_dtype),
-        interpret=interpret,
-    )(x, w)
+               block_cout: int = 128) -> jnp.ndarray:
+    return conv_call(x, w, style="mxu", block_cout=block_cout)
 
 
 def footprint(n, h, w, cin, kh, kw, cout, *, itemsize=1,
@@ -54,16 +31,14 @@ def footprint(n, h, w, cin, kh, kw, cout, *, itemsize=1,
     ho, wo = h - kh + 1, w - kw + 1
     bc = min(block_cout, cout)
     k = kh * kw * cin
-    vmem = (h * w * cin * itemsize
-            + ho * wo * k * itemsize          # im2col patches
-            + k * bc * itemsize
-            + ho * wo * bc * 4)
-    hbm = (n * h * w * cin * itemsize
-           + kh * kw * cin * cout * itemsize
-           + n * ho * wo * cout * 4)
+    vmem = conv_vmem(h, w, cin, kh, kw, cout, itemsize=itemsize,
+                     style="mxu", block_cout=block_cout)
+    hbm = conv_hbm(n, h, w, cin, kh, kw, cout, itemsize=itemsize,
+                   block_cout=block_cout)
     passes = n * ((cout + bc - 1) // bc)
-    cyc = n * mxu_pass_cycles(ho * wo, k, cout)
-    vpu = n * ho * wo * k                     # im2col data movement ops
+    cyc = conv_mxu_cycles(n, ho, wo, cin, kh, kw, cout, itemsize=itemsize,
+                          block_cout=block_cout)
+    vpu = n * ho * wo * k                     # shifted-slice data movement
     return Footprint(vmem_bytes=vmem, hbm_bytes=hbm, mxu_passes=passes,
                      vpu_ops=vpu,
                      est_cycles=cost_cycles(cyc, hbm),

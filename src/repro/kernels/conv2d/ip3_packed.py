@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
 from repro.core.resources import (Footprint, cost_cycles, hbm_cycles,
                                   vpu_op_cycles)
 
@@ -58,9 +59,9 @@ def _kernel(xa_ref, xb_ref, w_ref, oa_ref, ob_ref, *, kh: int, kw: int):
     ob_ref[0] = acc_b
 
 
-@functools.partial(jax.jit, static_argnames=("block_cout", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_cout",))
 def conv2d_ip3(xa: jnp.ndarray, xb: jnp.ndarray, w: jnp.ndarray, *,
-               block_cout: int = 128, interpret: bool = True):
+               block_cout: int = 128):
     if xa.dtype != jnp.int8 or xb.dtype != jnp.int8 or w.dtype != jnp.int8:
         raise TypeError("Conv3 is limited to 8-bit operands (paper Table I); "
                         f"got {xa.dtype}, {xb.dtype}, {w.dtype}")
@@ -79,7 +80,7 @@ def conv2d_ip3(xa: jnp.ndarray, xb: jnp.ndarray, w: jnp.ndarray, *,
         out_specs=[out, out],
         out_shape=[jax.ShapeDtypeStruct((n, ho, wo, cout), jnp.int32),
                    jax.ShapeDtypeStruct((n, ho, wo, cout), jnp.int32)],
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(xa, xb, w)
 
 

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro import kernels
 from repro.core.resources import (Footprint, cost_cycles, hbm_cycles,
                                   mxu_pass_cycles)
 
@@ -44,9 +45,9 @@ def _kernel(xa_ref, xb_ref, w_ref, oa_ref, ob_ref, *, kh: int, kw: int,
     ob_ref[0] = acc[1].reshape(ho, wo, -1)
 
 
-@functools.partial(jax.jit, static_argnames=("block_cout", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_cout",))
 def conv2d_ip4(xa: jnp.ndarray, xb: jnp.ndarray, w: jnp.ndarray, *,
-               block_cout: int = 128, interpret: bool = True):
+               block_cout: int = 128):
     n, h, w_, cin = xa.shape
     kh, kw, _, cout = w.shape
     ho, wo = h - kh + 1, w_ - kw + 1
@@ -64,7 +65,7 @@ def conv2d_ip4(xa: jnp.ndarray, xb: jnp.ndarray, w: jnp.ndarray, *,
         out_specs=[out, out],
         out_shape=[jax.ShapeDtypeStruct((n, ho, wo, cout), acc_dtype),
                    jax.ShapeDtypeStruct((n, ho, wo, cout), acc_dtype)],
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(xa, xb, w)
 
 

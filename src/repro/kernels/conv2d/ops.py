@@ -45,7 +45,7 @@ def _maybe_reduce(y: jnp.ndarray, reduce_axis: Optional[str],
 
 def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, ip: Optional[str] = None,
            budget: Optional[ResourceBudget] = None, ladder=(),
-           interpret: bool = True, reduce_axis: Optional[str] = None,
+           reduce_axis: Optional[str] = None,
            reduce: str = "psum", **tile_kwargs) -> jnp.ndarray:
     """Single-stream convolution through a selected IP (Conv1/Conv2).
 
@@ -69,21 +69,21 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, ip: Optional[str] = None,
         if planned.lowered:
             from repro.quant.ops import quantized_conv2d
             y = quantized_conv2d(x, w, bits=planned.precision_bits,
-                                 ip=planned.ip.name, interpret=interpret)
+                                 ip=planned.ip.name)
             return _maybe_reduce(y, reduce_axis, reduce)
         ip = planned.ip.name
     ip = ip.split(".")[-1]
     if ip not in _SINGLE:
         raise KeyError(f"{ip!r} is not a single-stream conv IP "
                        f"(have {sorted(_SINGLE)})")
-    y = _SINGLE[ip](x, w, interpret=interpret, **tile_kwargs)
+    y = _SINGLE[ip](x, w, **tile_kwargs)
     return _maybe_reduce(y, reduce_axis, reduce)
 
 
 def conv2d_dual(xa: jnp.ndarray, xb: jnp.ndarray, w: jnp.ndarray, *,
                 ip: Optional[str] = None,
-                budget: Optional[ResourceBudget] = None,
-                interpret: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                budget: Optional[ResourceBudget] = None
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Two parallel convolutions through a selected IP (Conv3/Conv4).
 
     No ``ladder=``: dual-stream callers already commit to a concrete
@@ -99,4 +99,4 @@ def conv2d_dual(xa: jnp.ndarray, xb: jnp.ndarray, w: jnp.ndarray, *,
     if ip not in _DUAL:
         raise KeyError(f"{ip!r} is not a dual-stream conv IP "
                        f"(have {sorted(_DUAL)})")
-    return _DUAL[ip](xa, xb, w, interpret=interpret)
+    return _DUAL[ip](xa, xb, w)

@@ -34,7 +34,7 @@ def fused_cnn_block(x: jnp.ndarray, w: jnp.ndarray, *,
                     pool_mode: str = "max", activation: str = "relu",
                     ip: Optional[str] = None,
                     budget: Optional[ResourceBudget] = None, ladder=(),
-                    interpret: bool = True, **tile_kwargs) -> jnp.ndarray:
+                    **tile_kwargs) -> jnp.ndarray:
     """conv -> pool -> activation as ONE launch through a selected member.
 
     ``tile_kwargs`` forward tiling parameters (``block_cout=``, typically
@@ -53,10 +53,8 @@ def fused_cnn_block(x: jnp.ndarray, w: jnp.ndarray, *,
             return quantized_fused_cnn_block(
                 x, w, pool_window=pool_window, pool_stride=pool_stride,
                 pool_mode=pool_mode, activation=activation,
-                bits=planned.precision_bits, ip=planned.ip.name,
-                interpret=interpret)
+                bits=planned.precision_bits, ip=planned.ip.name)
         ip = planned.ip.name
     return resolve_member(ip)(x, w, pool_window=tuple(pool_window),
                               pool_stride=pool_stride, pool_mode=pool_mode,
-                              act_kind=activation, interpret=interpret,
-                              **tile_kwargs)
+                              act_kind=activation, **tile_kwargs)

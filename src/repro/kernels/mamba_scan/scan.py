@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from repro.core.resources import (Footprint, cost_cycles, hbm_cycles,
                                   vpu_op_cycles)
 
@@ -31,28 +32,24 @@ def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref, h_ref, *,
     h_ref[...] = jnp.zeros_like(h_ref)
 
     def step(t, _):
-        # NB: all-slice indices only — a bare int here breaks interpret-mode
-        # state discharge on jax 0.4.x (`'int' object has no attribute
-        # 'shape'` in _load_discharge_rule).
-        tsl = (slice(None), pl.dslice(t, 1), slice(None))
-        x_t = pl.load(x_ref, tsl)[0, 0]    # (bdi,)
-        dt_t = pl.load(dt_ref, tsl)[0, 0]
-        b_t = pl.load(b_ref, tsl)[0, 0]    # (Ds,)
-        c_t = pl.load(c_ref, tsl)[0, 0]
+        tsl = (slice(None), pl.ds(t, 1), slice(None))
+        x_t = x_ref[tsl][0, 0]             # (bdi,)
+        dt_t = dt_ref[tsl][0, 0]
+        b_t = b_ref[tsl][0, 0]             # (Ds,)
+        c_t = c_ref[tsl][0, 0]
         dA = jnp.exp(dt_t[:, None] * a_ref[...])                     # (bdi,Ds)
         dBx = (dt_t * x_t)[:, None] * b_t[None, :]
         h_ref[...] = dA * h_ref[...] + dBx
         y_t = jnp.sum(h_ref[...] * c_t[None, :], axis=1)             # (bdi,)
-        pl.store(y_ref, tsl, y_t[None, None])
+        y_ref[tsl] = y_t[None, None]
         return 0
 
     jax.lax.fori_loop(0, T, step, 0)
     hout_ref[...] = h_ref[...][None]
 
 
-@functools.partial(jax.jit, static_argnames=("block_di", "interpret"))
-def selective_scan(x, dt, bp, cp, a, *, block_di: int = 256,
-                   interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("block_di",))
+def selective_scan(x, dt, bp, cp, a, *, block_di: int = 256):
     """x/dt: (B,T,Di); bp/cp: (B,T,Ds); a: (Di,Ds) -> (y (B,T,Di), h)."""
     B, T, Di = x.shape
     Ds = a.shape[1]
@@ -74,7 +71,7 @@ def selective_scan(x, dt, bp, cp, a, *, block_di: int = 256,
         out_shape=[jax.ShapeDtypeStruct((B, T, Di), jnp.float32),
                    jax.ShapeDtypeStruct((B, Di, Ds), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bdi, Ds), jnp.float32)],
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(f32(x), f32(dt), f32(bp), f32(cp), f32(a))
     return y, h
 

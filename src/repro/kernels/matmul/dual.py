@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from repro.core.resources import (Footprint, cost_cycles, hbm_cycles,
                                   mxu_pass_cycles)
 
@@ -41,7 +42,7 @@ def _dual_kernel(a1_ref, a2_ref, b_ref, o1_ref, o2_ref, acc1, acc2, *,
         o2_ref[...] = acc2[...].astype(o2_ref.dtype)
 
 
-def _mm_dual(a1, a2, b, *, bm, bn, bk, interpret, require_int8):
+def _mm_dual(a1, a2, b, *, bm, bn, bk, require_int8):
     m, k = a1.shape
     assert a1.shape == a2.shape
     _, n = b.shape
@@ -70,23 +71,19 @@ def _mm_dual(a1, a2, b, *, bm, bn, bk, interpret, require_int8):
         out_specs=[o_spec, o_spec],
         out_shape=[jax.ShapeDtypeStruct((mp, np_), acc_dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)] * 2,
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(a1, a2, b)
     return tuple(o[:m, :n] for o in out)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def mm_dual_shared(a1, a2, b, *, bm: int = 256, bn: int = 256, bk: int = 512,
-                   interpret: bool = True):
-    return _mm_dual(a1, a2, b, bm=bm, bn=bn, bk=bk, interpret=interpret,
-                    require_int8=True)
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
+def mm_dual_shared(a1, a2, b, *, bm: int = 256, bn: int = 256, bk: int = 512):
+    return _mm_dual(a1, a2, b, bm=bm, bn=bn, bk=bk, require_int8=True)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def mm_dual_full(a1, a2, b, *, bm: int = 256, bn: int = 256, bk: int = 512,
-                 interpret: bool = True):
-    return _mm_dual(a1, a2, b, bm=bm, bn=bn, bk=bk, interpret=interpret,
-                    require_int8=False)
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
+def mm_dual_full(a1, a2, b, *, bm: int = 256, bn: int = 256, bk: int = 512):
+    return _mm_dual(a1, a2, b, bm=bm, bn=bn, bk=bk, require_int8=False)
 
 
 def footprint_dual(m, k, n, *, itemsize=1, bm=256, bn=256, bk=512,

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from repro.core.resources import (Footprint, cost_cycles, hbm_cycles,
                                   mxu_pass_cycles, vpu_op_cycles)
 
@@ -48,9 +49,9 @@ def _pad2(x, b0, b1):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "out_dtype", "interpret"))
+                   static_argnames=("bm", "bn", "bk", "out_dtype"))
 def mm_mxu(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256, bn: int = 256,
-           bk: int = 512, out_dtype=None, interpret: bool = True) -> jnp.ndarray:
+           bk: int = 512, out_dtype=None) -> jnp.ndarray:
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
@@ -74,7 +75,7 @@ def mm_mxu(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256, bn: int = 256,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(a, b)[:m, :n]
 
 
@@ -85,9 +86,9 @@ def _mm_vpu_kernel(a_ref, b_ref, o_ref, *, acc_dtype):
     o_ref[...] = jnp.sum(a[:, :, None] * b[None, :, :], axis=1).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
-def mm_vpu(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 64, bn: int = 128,
-           interpret: bool = True) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("bm", "bn"))
+def mm_vpu(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 64,
+           bn: int = 128) -> jnp.ndarray:
     m, k = a.shape
     _, n = b.shape
     integer = (jnp.issubdtype(a.dtype, jnp.integer)
@@ -105,7 +106,7 @@ def mm_vpu(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 64, bn: int = 128,
                   pl.BlockSpec((k, bn), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), acc_dtype),
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(a, b)[:m, :n]
 
 

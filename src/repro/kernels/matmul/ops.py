@@ -20,7 +20,7 @@ _DUAL = {"mm_dual_shared": mm_dual_shared, "mm_dual_full": mm_dual_full}
 
 def matmul(a: jnp.ndarray, b: jnp.ndarray, *, ip: Optional[str] = None,
            budget: Optional[ResourceBudget] = None, ladder=(),
-           interpret: bool = True, **tile_kwargs) -> jnp.ndarray:
+           **tile_kwargs) -> jnp.ndarray:
     if ip is None:
         from repro.core.ip import SiteSpec
         from repro.core.plan import plan_single
@@ -30,17 +30,16 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray, *, ip: Optional[str] = None,
         if planned.lowered:
             from repro.quant.ops import quantized_matmul
             return quantized_matmul(a, b, bits=planned.precision_bits,
-                                    ip=planned.ip.name, interpret=interpret,
-                                    **tile_kwargs)
+                                    ip=planned.ip.name, **tile_kwargs)
         ip = planned.ip.name
     ip = ip.split(".")[-1]
-    return _SINGLE[ip](a, b, interpret=interpret, **tile_kwargs)
+    return _SINGLE[ip](a, b, **tile_kwargs)
 
 
 def matmul_dual(a1: jnp.ndarray, a2: jnp.ndarray, b: jnp.ndarray, *,
                 ip: Optional[str] = None,
                 budget: Optional[ResourceBudget] = None,
-                interpret: bool = True, **tile_kwargs):
+                **tile_kwargs):
     if ip is None:
         from repro.core.ip import SiteSpec
         from repro.core.plan import plan_single
@@ -48,4 +47,4 @@ def matmul_dual(a1: jnp.ndarray, a2: jnp.ndarray, b: jnp.ndarray, *,
                              a1.dtype, dual=True)
         ip = plan_single(spec, budget).ip.name
     ip = ip.split(".")[-1]
-    return _DUAL[ip](a1, a2, b, interpret=interpret, **tile_kwargs)
+    return _DUAL[ip](a1, a2, b, **tile_kwargs)

@@ -22,8 +22,7 @@ _MEMBERS = {"pool_vpu": pool2d_window, "pool_im2col": pool2d_im2col}
 
 def pool2d(x: jnp.ndarray, *, window=(2, 2), stride=None, mode: str = "max",
            ip: Optional[str] = None,
-           budget: Optional[ResourceBudget] = None, ladder=(),
-           interpret: bool = True) -> jnp.ndarray:
+           budget: Optional[ResourceBudget] = None, ladder=()) -> jnp.ndarray:
     """Max/avg pooling through a selected IP (Pool1/Pool2)."""
     if mode not in ("max", "avg"):
         raise ValueError(f"unknown pool mode {mode!r}; have ('max', 'avg')")
@@ -39,10 +38,9 @@ def pool2d(x: jnp.ndarray, *, window=(2, 2), stride=None, mode: str = "max",
             from repro.quant.ops import quantized_pool2d
             return quantized_pool2d(x, window=window, stride=stride,
                                     mode=mode, bits=planned.precision_bits,
-                                    ip=planned.ip.name, interpret=interpret)
+                                    ip=planned.ip.name)
         ip = planned.ip.name
     ip = ip.split(".")[-1]
     if ip not in _MEMBERS:
         raise KeyError(f"{ip!r} is not a pool2d IP (have {sorted(_MEMBERS)})")
-    return _MEMBERS[ip](x, window=window, stride=stride, mode=mode,
-                        interpret=interpret)
+    return _MEMBERS[ip](x, window=window, stride=stride, mode=mode)

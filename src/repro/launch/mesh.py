@@ -2,16 +2,30 @@
 
 Functions, not module constants — importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before first jax init).
+
+Every mesh, and every sharding ``distributed/sharding.to_shardings``
+builds, has ``Auto`` axes: the model code places arrays with sharding
+constraints and leaves the propagation to the compiler, which
+``jax.make_mesh``'s ``Explicit`` default would turn into per-op
+sharding-type errors (e.g. the embedding gather on a table sharded over
+``model``).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_axes(mesh):
+    """``mesh`` with every axis ``Auto`` (same devices, same names)."""
+    return jax.sharding.Mesh(mesh.devices, mesh.axis_names,
+                             axis_types=(AxisType.Auto,) * mesh.devices.ndim)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_axes(jax.make_mesh(shape, axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -19,7 +33,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_axes(jax.make_mesh((data, model), ("data", "model")))
 
 
 def mesh_axis_sizes(mesh) -> dict:

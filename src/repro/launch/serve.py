@@ -136,4 +136,6 @@ def serve(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     serve()

@@ -161,4 +161,6 @@ def train(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     train()

@@ -184,7 +184,7 @@ def cnn_block_site_specs(x_shape, w_shape, *, x_dtype, w_dtype=None,
 
 
 def _apply_fused_site(fused_s, p, x, *, pool_window, pool_stride, pool_mode,
-                      activation, interpret, plan, quant_report,
+                      activation, plan, quant_report,
                       tile_overrides):
     """Execute one planned fused site: the whole conv -> pool -> act
     chain in a single launch.  The lowered rungs run the quantized fused
@@ -199,14 +199,13 @@ def _apply_fused_site(fused_s, p, x, *, pool_window, pool_stride, pool_mode,
         y = quantized_fused_cnn_block(
             x, p["w"], pool_window=pool_window, pool_stride=pool_stride,
             pool_mode=pool_mode, activation=activation,
-            bits=fused_s.precision_bits, ip=fused_s.ip.name,
-            interpret=interpret)
+            bits=fused_s.precision_bits, ip=fused_s.ip.name)
     else:
         from repro.kernels.fused.ops import fused_cnn_block
         y = fused_cnn_block(x, p["w"], pool_window=pool_window,
                             pool_stride=pool_stride, pool_mode=pool_mode,
                             activation=activation, ip=fused_s.ip.name,
-                            interpret=interpret, **tile_kwargs)
+                            **tile_kwargs)
     if quant_report is not None:
         from repro.core.library import get_family
         from repro.quant.report import record
@@ -221,8 +220,8 @@ def _apply_fused_site(fused_s, p, x, *, pool_window, pool_stride, pool_mode,
 
 def apply_cnn_block(p, x, *, budget=None, pool_window=(2, 2),
                     pool_stride=None, pool_mode: str = "max",
-                    activation: str = "relu", interpret: bool = True,
-                    plan=None, site: str = "cnn_block", network=None,
+                    activation: str = "relu", plan=None,
+                    site: str = "cnn_block", network=None,
                     ladder=(), quant_report=None, tile_overrides=None,
                     fuse: bool = True):
     """One adaptive CNN layer: conv -> pool -> activation.
@@ -298,7 +297,7 @@ def apply_cnn_block(p, x, *, budget=None, pool_window=(2, 2),
         return _apply_fused_site(
             network.site(f"{site}.fused"), p, x, pool_window=pool_window,
             pool_stride=pool_stride, pool_mode=pool_mode,
-            activation=activation, interpret=interpret, plan=plan,
+            activation=activation, plan=plan,
             quant_report=quant_report, tile_overrides=tile_overrides)
 
     conv_s = network.site(f"{site}.conv")
@@ -330,10 +329,9 @@ def apply_cnn_block(p, x, *, budget=None, pool_window=(2, 2),
         # int8 returns the raw accumulator + scale (the dequantize fuses
         # into the next stage); 16-bit fake-quant returns (float, None).
         y, qscale = quantized_conv2d(x, p["w"], bits=conv_s.precision_bits,
-                                     ip=conv_s.ip.name, interpret=interpret,
-                                     return_scale=True)
+                                     ip=conv_s.ip.name, return_scale=True)
     else:
-        y = conv2d(x, p["w"], ip=conv_s.ip.name, interpret=interpret,
+        y = conv2d(x, p["w"], ip=conv_s.ip.name,
                    **dict((tile_overrides or {}).get(conv_s.spec.name, {})))
     if quant_report is not None:
         got = y if qscale is None else y.astype(jnp.float32) * qscale
@@ -348,7 +346,7 @@ def apply_cnn_block(p, x, *, budget=None, pool_window=(2, 2),
         from repro.quant.quantize import quantize_acts
         yq = quantize_acts(y.astype(jnp.float32) * qscale, bits=8)
         y = pool2d(yq.q, window=pool_window, stride=pool_stride,
-                   mode=pool_mode, ip=pool_s.ip.name, interpret=interpret)
+                   mode=pool_mode, ip=pool_s.ip.name)
         qscale = yq.scale
     else:
         if qscale is not None:  # widths disagree: dequantize boundary
@@ -359,11 +357,10 @@ def apply_cnn_block(p, x, *, budget=None, pool_window=(2, 2),
             y = quantized_pool2d(y, window=pool_window, stride=pool_stride,
                                  mode=pool_mode,
                                  bits=pool_s.precision_bits,
-                                 ip=pool_s.ip.name, interpret=interpret)
+                                 ip=pool_s.ip.name)
         else:
             y = pool2d(y, window=pool_window, stride=pool_stride,
-                       mode=pool_mode, ip=pool_s.ip.name,
-                       interpret=interpret)
+                       mode=pool_mode, ip=pool_s.ip.name)
     if quant_report is not None:
         ref = pool_ref(ref)
         got = y if qscale is None else y.astype(jnp.float32) * qscale
@@ -375,8 +372,7 @@ def apply_cnn_block(p, x, *, budget=None, pool_window=(2, 2),
             and act_s.precision_bits == pool_s.precision_bits):
         # relu(q * s) == relu(q) * s for s > 0: the activation runs on
         # the codes and the whole lowered chain dequantizes ONCE here.
-        y = activation_op(y, kind="relu", ip=act_s.ip.name,
-                          interpret=interpret)
+        y = activation_op(y, kind="relu", ip=act_s.ip.name)
         y = y * qscale
         qscale = None
     else:
@@ -387,10 +383,9 @@ def apply_cnn_block(p, x, *, budget=None, pool_window=(2, 2),
             from repro.quant.ops import quantized_activation
             y = quantized_activation(y, kind=activation,
                                      bits=act_s.precision_bits,
-                                     ip=act_s.ip.name, interpret=interpret)
+                                     ip=act_s.ip.name)
         else:
-            y = activation_op(y, kind=activation, ip=act_s.ip.name,
-                              interpret=interpret)
+            y = activation_op(y, kind=activation, ip=act_s.ip.name)
     if quant_report is not None:
         from repro.kernels.activation.ref import activation_ref
         ref = activation_ref(ref, kind=activation)

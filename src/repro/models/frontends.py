@@ -86,8 +86,8 @@ def cnn_frontend_site_specs(p, image_shape, image_dtype, *,
 
 
 def apply_cnn_frontend(p, images, *, budget=None, pool_window=(2, 2),
-                       activation: str = "relu", interpret: bool = True,
-                       plan=None, ladder=(), quant_report=None,
+                       activation: str = "relu", plan=None, ladder=(),
+                       quant_report=None,
                        network=None, tile_overrides=None,
                        fuse: bool = True):
     """images: (B, H, W, Cin) -> patch embeddings (B, S, d_model).
@@ -127,14 +127,15 @@ def apply_cnn_frontend(p, images, *, budget=None, pool_window=(2, 2),
     x = images
     for li, bp in enumerate(p["blocks"]):
         x = apply_cnn_block(bp, x, pool_window=pool_window,
-                            activation=activation, interpret=interpret,
-                            plan=plan, site=f"frontend.block{li}",
+                            activation=activation, plan=plan,
+                            site=f"frontend.block{li}",
                             network=network, ladder=ladder,
                             quant_report=quant_report,
                             tile_overrides=tile_overrides)
     b, h, w, c = x.shape
     tokens = x.reshape(b, h * w, c)
-    return jnp.einsum("bsc,cd->bsd", tokens, p["proj"].astype(x.dtype))
+    return jnp.einsum("bsc,cd->bsd", tokens, p["proj"].astype(x.dtype),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def make_inputs(cfg: ModelConfig, shape: ShapeConfig, *, seed: int = 0,

@@ -41,7 +41,7 @@ def _check_bits(bits: int) -> None:
 
 
 def quantized_conv2d(x: jnp.ndarray, w: jnp.ndarray, *, bits: int = 8,
-                     ip: Optional[str] = None, interpret: bool = True,
+                     ip: Optional[str] = None,
                      act_scale: Optional[jnp.ndarray] = None,
                      return_scale: bool = False):
     """conv2d with operands quantized to ``bits``; f32 result.
@@ -62,19 +62,19 @@ def quantized_conv2d(x: jnp.ndarray, w: jnp.ndarray, *, bits: int = 8,
     if bits == 8:
         xq = quantize_acts(x, bits=8, scale=act_scale)
         wq = quantize_weights(w, axis=-1, bits=8)
-        acc = conv2d(xq.q, wq.q, ip=ip, interpret=interpret)
+        acc = conv2d(xq.q, wq.q, ip=ip)
         scale = xq.scale * wq.scale.reshape(1, 1, 1, -1)
         if return_scale:
             return acc, scale
         return acc.astype(jnp.float32) * scale
     y = conv2d(fake_quant(x, bits=bits), fake_quant(w, bits=bits, axis=-1),
-               ip=ip, interpret=interpret)
+               ip=ip)
     return (y, None) if return_scale else y
 
 
 def quantized_pool2d(x: jnp.ndarray, *, window=(2, 2), stride=None,
                      mode: str = "max", bits: int = 8,
-                     ip: Optional[str] = None, interpret: bool = True,
+                     ip: Optional[str] = None,
                      act_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """pool2d over intN codes; f32 result.
 
@@ -86,16 +86,14 @@ def quantized_pool2d(x: jnp.ndarray, *, window=(2, 2), stride=None,
     from repro.kernels.pool2d.ops import pool2d
     if bits == 8:
         xq = quantize_acts(x, bits=8, scale=act_scale)
-        y = pool2d(xq.q, window=window, stride=stride, mode=mode, ip=ip,
-                   interpret=interpret)
+        y = pool2d(xq.q, window=window, stride=stride, mode=mode, ip=ip)
         return y.astype(jnp.float32) * xq.scale
     return pool2d(fake_quant(x, bits=bits), window=window, stride=stride,
-                  mode=mode, ip=ip, interpret=interpret)
+                  mode=mode, ip=ip)
 
 
 def quantized_activation(x: jnp.ndarray, *, kind: str = "relu",
                          bits: int = 8, ip: Optional[str] = None,
-                         interpret: bool = True,
                          act_scale: Optional[jnp.ndarray] = None
                          ) -> jnp.ndarray:
     """Activation evaluated on the intN-quantized input grid; f32 result.
@@ -108,7 +106,7 @@ def quantized_activation(x: jnp.ndarray, *, kind: str = "relu",
     _check_bits(bits)
     from repro.kernels.activation.ops import activation
     xq = quantize_acts(x, bits=bits, scale=act_scale)
-    return activation(dequantize(xq), kind=kind, ip=ip, interpret=interpret)
+    return activation(dequantize(xq), kind=kind, ip=ip)
 
 
 def quantized_fused_cnn_block(x: jnp.ndarray, w: jnp.ndarray, *,
@@ -116,7 +114,6 @@ def quantized_fused_cnn_block(x: jnp.ndarray, w: jnp.ndarray, *,
                               pool_mode: str = "max",
                               activation: str = "relu", bits: int = 8,
                               ip: Optional[str] = None,
-                              interpret: bool = True,
                               act_scale: Optional[jnp.ndarray] = None
                               ) -> jnp.ndarray:
     """Fused conv->pool->act with operands quantized to ``bits``; f32
@@ -140,17 +137,16 @@ def quantized_fused_cnn_block(x: jnp.ndarray, w: jnp.ndarray, *,
         return member(xq.q, wq.q, scale,
                       pool_window=tuple(pool_window),
                       pool_stride=pool_stride,
-                      pool_mode=pool_mode, act_kind=activation,
-                      interpret=interpret)
+                      pool_mode=pool_mode, act_kind=activation)
     return fused_cnn_block(fake_quant(x, bits=bits),
                            fake_quant(w, bits=bits, axis=-1),
                            pool_window=pool_window, pool_stride=pool_stride,
                            pool_mode=pool_mode, activation=activation,
-                           ip=ip, interpret=interpret)
+                           ip=ip)
 
 
 def quantized_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bits: int = 8,
-                     ip: Optional[str] = None, interpret: bool = True,
+                     ip: Optional[str] = None,
                      act_scale: Optional[jnp.ndarray] = None,
                      **tile_kwargs) -> jnp.ndarray:
     """a @ b with operands quantized to ``bits``; f32 result.
@@ -163,8 +159,8 @@ def quantized_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bits: int = 8,
     if bits == 8:
         aq = quantize_acts(a, bits=8, scale=act_scale)
         bq = quantize_weights(b, axis=-1, bits=8)
-        acc = matmul(aq.q, bq.q, ip=ip, interpret=interpret, **tile_kwargs)
+        acc = matmul(aq.q, bq.q, ip=ip, **tile_kwargs)
         scale = aq.scale * bq.scale.reshape(1, -1)
         return acc.astype(jnp.float32) * scale
     return matmul(fake_quant(a, bits=bits), fake_quant(b, bits=bits, axis=-1),
-                  ip=ip, interpret=interpret, **tile_kwargs)
+                  ip=ip, **tile_kwargs)

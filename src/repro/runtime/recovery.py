@@ -70,7 +70,6 @@ def server_state(server: AdaptiveServer,
             "rebalance_threshold": server.arbiter.rebalance_threshold,
             "max_batch": server.max_batch,
             "autotune": server.autotune,
-            "interpret": server.interpret,
             "demand_alpha": server.arbiter.demand_alpha,
             "fuse": server.fuse,
             "mesh": (dataclasses.asdict(server.mesh)
@@ -141,7 +140,7 @@ def recover_server(ckpt_dir: str, *, step: Optional[int] = None,
         ResourceBudget(**cfg["budget"]), policy=cfg["policy"],
         rebalance_threshold=cfg["rebalance_threshold"],
         max_batch=cfg["max_batch"], autotune=cfg["autotune"],
-        interpret=cfg["interpret"], demand_alpha=cfg["demand_alpha"],
+        demand_alpha=cfg["demand_alpha"],
         fuse=cfg["fuse"], calibration=calibration, mesh=mesh,
         slo_pressure=cfg.get("slo_pressure", 0.0),
         miss_alpha=cfg.get("miss_alpha", 0.5),

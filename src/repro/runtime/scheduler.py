@@ -47,6 +47,7 @@ import heapq
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.obs.trace import log_event
@@ -307,6 +308,9 @@ class SLOScheduler:
         budget_s = min(a.deadline_wall for a in take) - self.wall()
         comps = self.server._execute([a.req for a in take],
                                      deadline_budget_s=max(budget_s, 0.0))
+        # JAX returns once the kernels are enqueued: stamp completion
+        # only after the outputs exist on the device
+        jax.block_until_ready([c.result for c in comps if c.ok])
         w = self.wall()
         walls = [w - a.admitted_wall for a in take]
         missed = failed = 0

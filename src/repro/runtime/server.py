@@ -34,11 +34,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.plan import (STATS, network_min_fraction, plan_network,
                              replan)
-from repro.core.resources import MeshSpec, ResourceBudget
+from repro.core.resources import MeshSpec, ResourceBudget, check_device
 from repro.models.frontends import apply_cnn_frontend, cnn_frontend_site_specs
 from repro.obs.trace import NOOP_SPAN, TRACER, log_event
 from repro.runtime.arbiter import BudgetArbiter, TenantShare
@@ -98,11 +99,12 @@ class AdaptiveServer:
     def __init__(self, budget: Optional[ResourceBudget] = None, *,
                  policy: str = "demand", rebalance_threshold: float = 0.05,
                  max_batch: int = 4, autotune: bool = False,
-                 interpret: bool = True, demand_alpha: float = 0.5,
+                 demand_alpha: float = 0.5,
                  fuse: bool = True, calibration=None,
                  mesh: Optional[MeshSpec] = None,
                  slo_pressure: float = 0.0, miss_alpha: float = 0.5,
                  grant_quantum: float = 0.0):
+        check_device()
         self.budget = budget or ResourceBudget()
         # fuse (default True): serve every tenant through fusion-aware
         # plans — a block the planner can fuse runs conv->pool->act as
@@ -133,7 +135,6 @@ class AdaptiveServer:
         self.mesh = self.arbiter.mesh
         self.max_batch = max_batch
         self.autotune = autotune
-        self.interpret = interpret
         self.clock = 0.0
         self.tenants: Dict[str, Tenant] = {}
         self._queue = ShapeBucketQueue()
@@ -318,6 +319,31 @@ class AdaptiveServer:
         if boom is not None:
             raise boom
 
+    def _plan(self, tenant: Tenant, batch_shape, dtype, ladder):
+        """Plan one batch shape under the tenant's *current* slice.
+        Returns ``(specs, slice_budget, tenant_mesh, plan)``."""
+        slice_budget, tenant_mesh = self._tenant_budget(tenant)
+        skey = (tenant.name, tuple(batch_shape), str(dtype), ladder)
+        specs = self._specs_cache.get(skey)
+        if specs is None:
+            specs = self._specs(tenant.params, batch_shape, dtype,
+                                tenant.pool_window, tenant.activation,
+                                ladder)
+            if len(self._specs_cache) >= _SIDE_CACHE_MAX:
+                self._specs_cache.pop(next(iter(self._specs_cache)))
+            self._specs_cache[skey] = specs
+        plan = replan(specs, slice_budget, fuse=self.fuse,
+                      calibration=self.calibration, mesh=tenant_mesh)
+        return specs, slice_budget, tenant_mesh, plan
+
+    def plan_for(self, name: str, batch: int):
+        """The plan a float32 batch of ``batch`` samples of tenant
+        ``name`` runs under its current grant (a plan-cache hit once
+        such a batch has been served)."""
+        tenant = self.tenants[name]
+        return self._plan(tenant, (batch,) + tenant.input_shape,
+                          jnp.dtype("float32"), tenant.ladder)[3]
+
     def _attempt(self, tenant: Tenant, xb, *, retry_f32: bool = False):
         """One execution attempt: route injected faults, (re)plan under
         the tenant's *current* slice — a degraded mesh re-plans here —
@@ -326,19 +352,9 @@ class AdaptiveServer:
         precision ladder off (the guard's non-finite fallback)."""
         if INJECTOR.enabled:
             self._route_execute_faults(tenant)
-        slice_budget, tenant_mesh = self._tenant_budget(tenant)
         ladder = () if retry_f32 else tenant.ladder
-        skey = (tenant.name, xb.shape, str(xb.dtype), ladder)
-        specs = self._specs_cache.get(skey)
-        if specs is None:
-            specs = self._specs(tenant.params, xb.shape, xb.dtype,
-                                tenant.pool_window, tenant.activation,
-                                ladder)
-            if len(self._specs_cache) >= _SIDE_CACHE_MAX:
-                self._specs_cache.pop(next(iter(self._specs_cache)))
-            self._specs_cache[skey] = specs
-        plan = replan(specs, slice_budget, fuse=self.fuse,
-                      calibration=self.calibration, mesh=tenant_mesh)
+        specs, slice_budget, tenant_mesh, plan = self._plan(
+            tenant, xb.shape, xb.dtype, ladder)
         if INJECTOR.enabled and tenant_mesh is not None:
             INJECTOR.check_devices(*self.arbiter.device_slice(tenant.name))
         tile_overrides = None
@@ -362,10 +378,11 @@ class AdaptiveServer:
                 y = self._run_frontend_sharded(
                     tenant, xb, plan, tile_overrides=tile_overrides)
             else:
+                if tenant_mesh is not None:
+                    xb = jax.device_put(xb, self._granted_device(tenant))
                 y = apply_cnn_frontend(tenant.params, xb, network=plan,
                                        pool_window=tenant.pool_window,
                                        activation=tenant.activation,
-                                       interpret=self.interpret,
                                        ladder=ladder,
                                        quant_report=quant_report,
                                        tile_overrides=tile_overrides,
@@ -443,9 +460,10 @@ class AdaptiveServer:
         path: a mesh plan whose sites are ALL batch-sharded at the mesh
         degree (a uniform layout needs no mid-chain relays inside the
         frontend walk), float precision, and a batch that tiles evenly.
-        Mixed/chan/degree-1 layouts fall back to the replicated walk of
-        the same plan — identical math, the mesh then only reshapes the
-        time model."""
+        Mixed/chan/degree-1 layouts run the replicated walk of the same
+        plan on the first device of the tenant's slice
+        (``_granted_device``) — identical math, the mesh then only
+        reshapes the time model."""
         if plan.mesh is None or plan.mesh.devices <= 1:
             return False
         d = plan.mesh.devices
@@ -456,6 +474,18 @@ class AdaptiveServer:
                or s.lowered for s in plan.sites):
             return False
         return xb.shape[0] % d == 0
+
+    def _granted_device(self, tenant: Tenant):
+        """The first device of the tenant's granted slice, where a mesh
+        plan that is not uniformly batch-sharded runs.  A slice past the
+        devices this process has is refused."""
+        start, _stop = self.arbiter.device_slice(tenant.name)
+        devices = jax.devices()
+        if start >= len(devices):
+            raise ValueError(
+                f"tenant {tenant.name!r} is granted device {start} but "
+                f"only {len(devices)} exist")
+        return devices[start]
 
     def _run_frontend_sharded(self, tenant: Tenant, xb, plan,
                               *, tile_overrides=None):
@@ -468,7 +498,7 @@ class AdaptiveServer:
         slice comes from ``fault_tolerance.elastic_remesh`` — the same
         builder the degraded path re-meshes through after a device
         loss."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.runtime.fault_tolerance import elastic_remesh
         d = plan.mesh.devices
@@ -480,12 +510,11 @@ class AdaptiveServer:
             return apply_cnn_frontend(tenant.params, xg, network=dplan,
                                       pool_window=tenant.pool_window,
                                       activation=tenant.activation,
-                                      interpret=self.interpret,
                                       tile_overrides=tile_overrides)
 
         fn = shard_map(device_fn, mesh=mesh,
                        in_specs=(P(plan.mesh.axis),),
-                       out_specs=P(plan.mesh.axis), check_rep=False)
+                       out_specs=P(plan.mesh.axis), check_vma=False)
         y = fn(xb)
         if INJECTOR.enabled:
             # injection seam "collective": the gathered result of a

@@ -31,7 +31,7 @@ def run_sub(body: str, n_dev: int = 8, timeout: int = 420) -> str:
 
 def test_ring_all_reduce_equals_psum():
     run_sub("""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.distributed.collectives import ring_all_reduce
         mesh = jax.make_mesh((8,), ("data",))
         x = jnp.arange(8 * 6, dtype=jnp.float32).reshape(8, 6)
@@ -43,17 +43,17 @@ def test_ring_all_reduce_equals_psum():
             return jax.lax.psum(xl, "data")
 
         got = shard_map(ring, mesh=mesh, in_specs=P("data"),
-                        out_specs=P("data"), check_rep=False)(x)
+                        out_specs=P("data"), check_vma=False)(x)
         want = shard_map(ref, mesh=mesh, in_specs=P("data"),
-                         out_specs=P("data"), check_rep=False)(x)
+                         out_specs=P("data"), check_vma=False)(x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6)
         # odd payload size exercises the padding path
         y = jnp.arange(8 * 7, dtype=jnp.float32).reshape(8, 7)
         got = shard_map(ring, mesh=mesh, in_specs=P("data"),
-                        out_specs=P("data"), check_rep=False)(y)
+                        out_specs=P("data"), check_vma=False)(y)
         want = shard_map(ref, mesh=mesh, in_specs=P("data"),
-                         out_specs=P("data"), check_rep=False)(y)
+                         out_specs=P("data"), check_vma=False)(y)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6)
         print("ring OK")
@@ -62,7 +62,7 @@ def test_ring_all_reduce_equals_psum():
 
 def test_bucketed_psum_matches_fused():
     run_sub("""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.distributed.collectives import bucketed_psum
         mesh = jax.make_mesh((8,), ("data",))
         tree = {"a": jnp.ones((8, 4)), "b": jnp.arange(8.0).reshape(8, 1),
@@ -72,11 +72,11 @@ def test_bucketed_psum_matches_fused():
             return bucketed_psum(t, "data", n_buckets=2)
 
         got = shard_map(f, mesh=mesh, in_specs=P("data"),
-                        out_specs=P("data"), check_rep=False)(tree)
+                        out_specs=P("data"), check_vma=False)(tree)
         want = shard_map(lambda t: jax.tree.map(
                              lambda x: jax.lax.psum(x, "data"), t),
                          mesh=mesh, in_specs=P("data"),
-                         out_specs=P("data"), check_rep=False)(tree)
+                         out_specs=P("data"), check_vma=False)(tree)
         for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             np.testing.assert_allclose(np.asarray(g), np.asarray(w))
         print("bucketed OK")
@@ -109,16 +109,16 @@ def test_ring_all_reduce_padding_and_dtypes():
     (the padding path), a 1-device axis (identity), and integer dtypes —
     int sums are associative, so ring and psum must agree BIT-exactly."""
     run_sub("""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.distributed.collectives import ring_all_reduce
 
         def both(mesh, axis, x):
             ring = shard_map(lambda v: ring_all_reduce(v, axis), mesh=mesh,
                              in_specs=P(axis), out_specs=P(axis),
-                             check_rep=False)(x)
+                             check_vma=False)(x)
             ref = shard_map(lambda v: jax.lax.psum(v, axis), mesh=mesh,
                             in_specs=P(axis), out_specs=P(axis),
-                            check_rep=False)(x)
+                            check_vma=False)(x)
             return np.asarray(ring), np.asarray(ref)
 
         mesh8 = jax.make_mesh((8,), ("data",))

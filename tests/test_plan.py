@@ -122,10 +122,10 @@ def test_partition_repair_rescues_starved_site():
         SiteSpec.make("small.conv", "conv2d",
                       ((1, 16, 16, 8), (3, 3, 8, 16)), "int8", dual=False),
     ]
-    # big ip1 needs ~133 KiB vmem, small ~15 KiB; big's cost share is
-    # ~99%, so under a 200 KiB envelope the small site's proportional
-    # slice (~3 KiB) fits nothing.
-    budget = ResourceBudget(vmem_bytes=200 * 1024)
+    # big ip2 needs ~528 KiB vmem, small ~304 KiB; big's cost share is
+    # ~99%, so under a 1 MiB envelope the small site's proportional
+    # slice (~10 KiB) fits nothing.
+    budget = ResourceBudget(vmem_bytes=2**20)
     plan = plan_network(specs, budget)
     small = plan.site("small.conv")
     assert small.footprint.fits(budget.scaled(small.fraction))
@@ -134,17 +134,17 @@ def test_partition_repair_rescues_starved_site():
 
 
 def test_no_feasible_partition_raises():
-    # Each site alone fits the envelope (~133 KiB need vs 200 KiB), but
-    # eight of them jointly demand ~5x it.
+    # Each site alone fits the envelope (~528 KiB need vs 1 MiB), but
+    # eight of them jointly demand ~4x it.
     specs = [
         SiteSpec.make(f"c{i}.conv", "conv2d",
                       ((4, 32, 32, 16), (3, 3, 16, 32)), "int8", dual=False)
         for i in range(8)
     ]
-    single = plan_network(specs[:1], ResourceBudget(vmem_bytes=200 * 1024))
+    single = plan_network(specs[:1], ResourceBudget(vmem_bytes=2**20))
     assert len(single) == 1
     with pytest.raises(ValueError, match="no feasible network plan"):
-        plan_network(specs, ResourceBudget(vmem_bytes=200 * 1024))
+        plan_network(specs, ResourceBudget(vmem_bytes=2**20))
 
 
 def test_site_infeasible_under_full_budget_raises_family_error():

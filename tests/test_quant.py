@@ -147,12 +147,12 @@ def _conv_site(ladder=(), name="c.conv"):
 def test_ladder_descends_only_on_failure():
     ample = ResourceBudget()
     assert plan_single(_conv_site(ladder=(16, 8)), ample).precision_bits == 32
-    tight = ResourceBudget(vmem_bytes=17 * 1024)
+    tight = ResourceBudget(vmem_bytes=320 * 1024)
     with pytest.raises(ValueError, match="no feasible"):
         plan_single(_conv_site(), tight)
     planned = plan_single(_conv_site(ladder=(16, 8)), tight)
     assert planned.precision_bits == 8 and planned.lowered
-    mid = ResourceBudget(vmem_bytes=20 * 1024)
+    mid = ResourceBudget(vmem_bytes=400 * 1024)
     assert plan_single(_conv_site(ladder=(16, 8)), mid).precision_bits == 16
 
 
@@ -196,8 +196,8 @@ def test_mixed_precision_plan_json_round_trip():
                       kind="relu"),
     ]
     # fuse=False: the squeeze that forces mixed precision targets the
-    # per-op footprints (the fused group fits 40 KiB without lowering)
-    plan = plan_network(specs, ResourceBudget(vmem_bytes=40 * 1024),
+    # per-op footprints (the fused group fits 780 KiB without lowering)
+    plan = plan_network(specs, ResourceBudget(vmem_bytes=780 * 1024),
                         fuse=False)
     bits = {s.spec.name: s.precision_bits for s in plan.sites}
     assert any(s.lowered for s in plan.sites)
@@ -229,7 +229,7 @@ def test_ops_wrapper_executes_lowered_plan(rng):
     x = _randn(rng, CONV_X)
     w = _randn(rng, CONV_W, scale=0.1)
     ref = conv2d_ref(x, w)
-    y = conv2d(x, w, budget=ResourceBudget(vmem_bytes=17 * 1024),
+    y = conv2d(x, w, budget=ResourceBudget(vmem_bytes=320 * 1024),
                ladder=(16, 8))
     assert y.dtype == jnp.float32
     assert relative_error(y, ref) < 5e-2
@@ -240,9 +240,9 @@ def test_apply_cnn_block_mixed_precision_end_to_end(rng):
     block = init_cnn_block(jax.random.PRNGKey(0), cin=8, cout=16, k=3)
     x = _randn(rng, CONV_X)
     y_f32 = apply_cnn_block(block, x, activation="relu")
-    # fuse=False below: 28 KiB starves the per-op sites (the fused
+    # fuse=False below: 780 KiB starves the per-op sites (the fused
     # group's smaller working set would still fit at f32)
-    tight = ResourceBudget(vmem_bytes=28 * 1024)
+    tight = ResourceBudget(vmem_bytes=780 * 1024)
     with pytest.raises(ValueError, match="no feasible"):
         apply_cnn_block(block, x, budget=tight, activation="relu",
                         fuse=False)
@@ -267,7 +267,7 @@ def test_apply_cnn_frontend_with_ladder(rng):
     imgs = _randn(rng, (2, 16, 16, 3))
     y_f32 = apply_cnn_frontend(p, imgs)
     report = {}
-    y = apply_cnn_frontend(p, imgs, budget=ResourceBudget(vmem_bytes=64
+    y = apply_cnn_frontend(p, imgs, budget=ResourceBudget(vmem_bytes=1000
                                                           * 1024),
                            ladder=(16, 8), quant_report=report, fuse=False)
     assert y.shape == y_f32.shape

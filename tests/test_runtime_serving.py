@@ -204,9 +204,12 @@ def test_server_batch_submission_fans_out(rng):
 def test_squeezed_tenant_descends_ladder_within_error_bound(rng):
     clear_plan_cache()
     # fuse=False: the squeeze thresholds below were sized against the
-    # per-op footprints — the fused group fits the slice without lowering
-    srv = AdaptiveServer(SERVING_DEVICE, policy="demand", max_batch=4,
-                         fuse=False)
+    # per-op footprints — the fused group fits the slice without lowering.
+    # 16 MiB of VMEM (the compiler's default scoped limit) is what makes
+    # the light tenant's slice too small for its f32 working set.
+    device = ResourceBudget(vmem_bytes=16 * 2**20,
+                            vpu_ops_budget=SERVING_DEVICE.vpu_ops_budget)
+    srv = AdaptiveServer(device, policy="demand", max_batch=4, fuse=False)
     srv.register("heavy", _frontend(0, channels=(8, 16), d_model=32),
                  (32, 32, 8))
     srv.register("light", _frontend(1), (24, 24, 6), activation="tanh",

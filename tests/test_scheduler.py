@@ -214,6 +214,29 @@ def test_wall_clock_judges_misses_not_the_model_clock(rng):
     assert srv.arbiter.miss_rate("t") > 0.0
 
 
+def test_wall_completion_stamped_after_device_wait(rng, monkeypatch):
+    """Completion is stamped on the wall clock only after the batch's
+    outputs are ready on the device: the (fake) device wait advances the
+    clock by 5 s, and every request's wall latency must include it."""
+    wall = FakeWall()
+    srv, sched = _deployment(wall=wall, deadline_s=60.0)
+    waited = []
+    real_wait = jax.block_until_ready
+
+    def device_wait(x):
+        waited.append(len(x))
+        wall.advance(5.0)
+        return real_wait(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", device_wait)
+    for _ in range(4):
+        sched.submit("t", _sample(rng))
+    comps = sched.run()
+    assert waited == [4]                         # one wait, the whole batch
+    assert all(c.ok for c in comps)
+    assert list(srv.tenants["t"].telemetry.wall_latencies) == [5.0] * 4
+
+
 # --------------------------------------------------------------------------
 # Arbiter extensions the scheduler rides on
 # --------------------------------------------------------------------------

@@ -28,13 +28,14 @@ from repro.runtime.arbiter import BudgetArbiter
 
 REPO = Path(__file__).resolve().parent.parent
 MESH2 = MeshSpec(devices=2)
-# The MXU ration that forces the slow VPU member at 1 device — the
-# same win workload benchmarks/run.py::table_mesh measures.
+# The MXU ration that gates the MXU member at 1 device — the same
+# ration benchmarks/run.py::table_mesh measures under.
 WIN_BUDGET = ResourceBudget(mxu_passes_budget=7)
 
 
-def _conv(name="conv", x=(8, 16, 16, 32), w=(3, 3, 32, 128)):
-    return SiteSpec.make(name, "conv2d", (x, w), "float32", dual=False)
+def _conv(name="conv", x=(8, 16, 16, 32), w=(3, 3, 32, 128),
+          dtype="float32"):
+    return SiteSpec.make(name, "conv2d", (x, w), dtype, dual=False)
 
 
 def run_sub(body: str, n_dev: int = 2, timeout: int = 420) -> str:
@@ -61,7 +62,8 @@ def run_sub(body: str, n_dev: int = 2, timeout: int = 420) -> str:
 # --------------------------------------------------------------------------
 def test_split_wins_flips_member_and_cuts_cycles():
     clear_plan_cache()
-    spec = _conv()
+    # bf16 rows 32 wide: one MXU pass per tap-row beats the VPU member
+    spec = _conv(x=(8, 34, 34, 128), w=(3, 3, 128, 128), dtype="bfloat16")
     p1 = plan_network((spec,), WIN_BUDGET)
     p2 = plan_network((spec,), WIN_BUDGET, mesh=MESH2)
     s1, s2 = p1.sites[0], p2.sites[0]
@@ -88,10 +90,10 @@ def test_refusal_when_collectives_dominate():
 
 
 def test_sharding_rescues_single_device_infeasibility():
-    # 256 KiB vmem: no 1-device member fits, but the chan split's
+    # 600 KiB vmem: no 1-device member fits, but the chan split's
     # halved working set does — the mesh widens feasibility
     spec = _conv()
-    tight = ResourceBudget(vmem_bytes=256 * 1024)
+    tight = ResourceBudget(vmem_bytes=600 * 1024)
     with pytest.raises(ValueError, match="no feasible IP"):
         plan_network((spec,), tight)
     rescued = plan_network((spec,), tight, mesh=MESH2)
@@ -257,10 +259,38 @@ def test_sharded_fused_chain_matches_replicated():
     """)
 
 
+def test_unsharded_mesh_plan_runs_on_granted_device():
+    """Mesh mode, one device per tenant: each tenant's degree-1 plan runs
+    on the device of its own slice, not on the default device."""
+    run_sub("""
+        from repro.core.resources import MeshSpec, ResourceBudget
+        from repro.models.frontends import init_cnn_frontend
+        from repro.runtime import AdaptiveServer
+        srv = AdaptiveServer(ResourceBudget(), mesh=MeshSpec(devices=2),
+                             max_batch=2)
+        rng = np.random.default_rng(0)
+        for i, name in enumerate(("a", "b")):
+            srv.register(name, init_cnn_frontend(
+                jax.random.PRNGKey(i), channels=(6, 12), d_model=16),
+                (12, 12, 6))
+            srv.submit(name, rng.normal(size=(12, 12, 6)).astype(np.float32))
+        comps = srv.drain()
+        placed = set()
+        for c in comps:
+            start, stop = srv.arbiter.device_slice(c.tenant)
+            assert stop - start == 1
+            assert srv.plan_for(c.tenant, 1).sites[0].shard_degree == 1
+            assert c.result.devices() == {jax.devices()[start]}
+            placed.add(start)
+        assert placed == {0, 1}
+        print("placed OK")
+    """)
+
+
 def test_sharded_execution_refuses_lowered_plans():
     spec = SiteSpec.make("lo", "conv2d", ((2, 8, 8, 4), (3, 3, 4, 8)),
                          "float32", ladder=(16, 8), dual=False)
-    plan = plan_network((spec,), ResourceBudget(vmem_bytes=3 * 1024))
+    plan = plan_network((spec,), ResourceBudget(vmem_bytes=200 * 1024))
     assert plan.sites[0].lowered
     from repro.distributed.shard_exec import apply_plan_sharded
     with pytest.raises(ValueError, match="float-only"):
