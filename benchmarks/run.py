@@ -25,9 +25,9 @@ Paper tables (the reproduction targets):
       cost outweighs the split (refusal measured via the forced-shard
       counterfactual); runs under a forced 2-device host mesh
   table_obs                  — cross-layer observability: plan audits
-      must name concrete rejection reasons, a traced serving cycle must
-      export valid Chrome trace JSON (plan/kernel/arbiter spans) within
-      a bounded overhead of the untraced run, and the calibration drift
+      must name concrete rejection reasons, a serving cycle under the
+      profiler must put plan/dispatch/arbiter spans on its timeline
+      within a bounded overhead of the untraced run, and the calibration drift
       monitor must trip on a mis-scaled table while staying quiet on
       the honest fit (recalibration re-arms it)
   table_slo              — the SLO scheduler vs the synchronous round
@@ -52,7 +52,9 @@ Output: ``name,us_per_call,derived`` CSV rows on stdout.
 """
 from __future__ import annotations
 
+import collections
 import json
+import shutil
 import time
 from pathlib import Path
 
@@ -669,12 +671,14 @@ def table_mesh(smoke: bool = False):
 # (a) AUDIT: every site whose constrained-budget choice moved off the
 #     ample-budget first choice must carry a concrete, numbered
 #     rejection reason in the plan audit (NetworkPlan.explain());
-# (b) TRACE: a traced serving cycle must export valid Chrome
-#     trace-event JSON containing plan, kernel, and arbiter spans
-#     (written to experiments/obs/trace.json — load it in Perfetto);
-# (c) OVERHEAD: the same serving trace with the tracer on must stay
-#     within a bounded factor of the tracer-off run (the disabled path
-#     is allocation-free; the enabled path is one dict per span);
+# (b) TRACE: a serving cycle under the JAX profiler must put plan,
+#     dispatch and arbiter spans on the profiler's timeline (written
+#     to experiments/obs/trace/ with a perfetto_trace.json.gz — load it
+#     in Perfetto);
+# (c) OVERHEAD: the same serving trace with the profiler on must stay
+#     within a bounded factor of the profiler-off run (the disabled
+#     path is allocation-free; the enabled path is one annotation per
+#     span);
 # (d) DRIFT: a calibration table fit on honest measurements must stay
 #     quiet under the drift monitor while the same measurements against
 #     a mis-scaled copy of the table must trip it — and recalibrate()
@@ -683,7 +687,7 @@ def table_mesh(smoke: bool = False):
 # to experiments/obs/metrics.prom.
 # ---------------------------------------------------------------------------
 OBS_DRIFT_SCALE = 8.0          # the mis-scaled table's coefficient factor
-OBS_OVERHEAD_BOUND = 2.0       # tracer-on / tracer-off wall-clock ceiling
+OBS_OVERHEAD_BOUND = 2.0       # profiler-on / profiler-off wall-clock ceiling
 
 
 def _obs_serving_cycle(n_heavy=4, n_light=2):
@@ -720,10 +724,10 @@ def table_obs(smoke: bool = False):
                                            member_key)
     from repro.core.plan import clear_plan_cache, plan_network
     from repro.core.resources import ResourceBudget
-    from repro.obs import TRACER, DriftMonitor, mis_scaled_table
+    from repro.obs import DriftMonitor, mis_scaled_table
     print("# Table O — observability: plan audits name concrete "
-          "rejection reasons; a traced serving cycle exports valid "
-          "Chrome trace JSON with plan/kernel/arbiter spans within "
+          "rejection reasons; a serving cycle under the profiler puts "
+          "plan/dispatch/arbiter spans on its timeline within "
           f"{OBS_OVERHEAD_BOUND}x of the untraced run; the drift "
           "monitor stays quiet on the honest calibration table and "
           f"trips on a {OBS_DRIFT_SCALE}x mis-scaled copy, and "
@@ -767,28 +771,31 @@ def table_obs(smoke: bool = False):
          f"audit_ok={int(non_first == explained)}")
 
     # -- (b) + (c) traced serving cycle, then the overhead bound -----------
-    _, base_s = _obs_serving_cycle()          # warm compile, tracer off
+    _, base_s = _obs_serving_cycle()          # warm compile, untraced
     _, off_s = _obs_serving_cycle()
-    TRACER.clear()
-    TRACER.enable()
-    try:
+    trace_dir = out_dir / "trace"
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    with jax.profiler.trace(str(trace_dir), create_perfetto_trace=True):
         srv, on_s = _obs_serving_cycle()
-        metrics_text = srv.metrics().render()
-    finally:
-        TRACER.disable()
-    doc = json.loads(TRACER.export_chrome_trace())
-    assert isinstance(doc["traceEvents"], list) and doc["traceEvents"]
-    for ev in doc["traceEvents"]:
-        assert ev["ph"] in ("X", "i") and ev["name"] and "ts" in ev
-        if ev["ph"] == "X":
-            assert ev["dur"] >= 0.0
-    cats = {e["cat"] for e in doc["traceEvents"]}
-    missing = {"plan", "kernel", "arbiter"} - cats
-    assert not missing, f"trace is missing span categories: {missing}"
-    (out_dir / "trace.json").write_text(
-        TRACER.export_chrome_trace(indent=None))
+    metrics_text = srv.metrics().render()
+    (xplane,) = trace_dir.glob("**/*.xplane.pb")
+    names = collections.Counter(
+        ev.name for plane in jax.profiler.ProfileData.from_file(
+            str(xplane)).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events)
+    kinds = {"plan": ("plan_network", "replan", "serve.plan"),
+             "dispatch": ("serve.dispatch",),
+             "arbiter": ("arbiter.split",)}
+    missing = {k for k, spans in kinds.items()
+               if not any(names[n] for n in spans)}
+    assert not missing, f"trace is missing spans of: {missing}"
+    assert list(trace_dir.glob("**/perfetto_trace.json.gz")), \
+        "the profiler wrote no Perfetto timeline"
     (out_dir / "metrics.prom").write_text(metrics_text)
-    spans = sum(1 for e in doc["traceEvents"] if e["ph"] == "X")
+    program = sorted(n for n in names
+                     if n.startswith(("serve.", "arbiter."))
+                     or n in ("plan_network", "replan", "select"))
+    spans = sum(names[n] for n in program)
     ratio = on_s / max(off_s, 1e-9)
     overhead_ok = ratio < OBS_OVERHEAD_BOUND
     assert overhead_ok, (
@@ -796,8 +803,7 @@ def table_obs(smoke: bool = False):
         f"{OBS_OVERHEAD_BOUND}x bound (off={off_s * 1e6:.0f}us, "
         f"on={on_s * 1e6:.0f}us)")
     emit("table_obs.trace", on_s * 1e6,
-         f"trace_valid=1;spans={spans};events={len(doc['traceEvents'])}"
-         f";cats={'|'.join(sorted(cats))}"
+         f"trace_valid=1;spans={spans};names={'|'.join(program)}"
          f";off_us={off_s * 1e6:.0f};on_us={on_s * 1e6:.0f}"
          f";overhead_x={ratio:.2f};overhead_ok={int(overhead_ok)}")
 

@@ -7,10 +7,11 @@ Four views onto one small CNN serving stack, narrated end to end:
    rejected every candidate the selector passed over (vmem overflow,
    VPU starvation, precision-ladder descent) plus plan-level events
    (fusion decisions, partition repairs, shard refusals).
-2. TRACE   — enable the span tracer, run a multi-tenant serving cycle,
-   and export Chrome trace-event JSON (open it at ui.perfetto.dev):
-   plan/replan spans, kernel launches, arbiter splits, batch queue
-   waits.  Disabled, the tracer costs the hot loop nothing.
+2. TRACE   — run a multi-tenant serving cycle under the JAX profiler,
+   which writes the program's spans beside the device ops into one
+   Perfetto timeline (open it at ui.perfetto.dev): plan/replan spans,
+   frame stacks, kernel dispatch, arbiter splits, JAX compiles.  With
+   no profiler session the tracer costs the hot loop nothing.
 3. METRICS — render the server's state as Prometheus-style text:
    per-tenant request counts, latency quantiles, shard degree,
    comm-cycles share, plan-cache size.
@@ -24,7 +25,8 @@ benchmarks/run.py::table_obs for the asserted version of this loop.
 
     PYTHONPATH=src python examples/observability_demo.py
 """
-import json
+import collections
+import shutil
 import sys
 from pathlib import Path
 
@@ -38,8 +40,7 @@ from repro.core.calibrate_cost import (collect_plan_samples,  # noqa: E402
 from repro.core.plan import clear_plan_cache, plan_network  # noqa: E402
 from repro.core.resources import ResourceBudget  # noqa: E402
 from repro.models.blocks import cnn_block_site_specs  # noqa: E402
-from repro.obs import (EVENTS, TRACER, DriftMonitor,  # noqa: E402
-                       mis_scaled_table)
+from repro.obs import EVENTS, DriftMonitor, mis_scaled_table  # noqa: E402
 
 LAYERS = [(8, 16), (16, 32), (32, 32)]
 
@@ -104,25 +105,26 @@ def main():
     print("\n".join("  " + line
                     for line in tight.explain().splitlines()))
 
-    print("\n== 2. TRACE: a serving cycle under the span tracer ==")
+    print("\n== 2. TRACE: a serving cycle under the profiler ==")
+    import jax
     serving_cycle()                      # warm compile caches untraced
     EVENTS.clear()
-    TRACER.clear()
-    TRACER.enable()
-    try:
+    out = ROOT / "experiments" / "obs" / "demo_trace"
+    shutil.rmtree(out, ignore_errors=True)
+    with jax.profiler.trace(str(out), create_perfetto_trace=True):
         srv = serving_cycle()
-        metrics_text = srv.metrics().render()
-    finally:
-        TRACER.disable()
-    doc = json.loads(TRACER.export_chrome_trace())
-    cats = sorted({e["cat"] for e in doc["traceEvents"]})
-    out = ROOT / "experiments" / "obs"
-    out.mkdir(parents=True, exist_ok=True)
-    (out / "demo_trace.json").write_text(
-        TRACER.export_chrome_trace(indent=None))
-    print(f"  {len(doc['traceEvents'])} events over categories "
-          f"{'|'.join(cats)}")
-    print(f"  -> {out / 'demo_trace.json'} (load at ui.perfetto.dev)")
+    metrics_text = srv.metrics().render()
+    (xplane,) = out.glob("**/*.xplane.pb")
+    (timeline,) = out.glob("**/perfetto_trace.json.gz")
+    names = collections.Counter(
+        ev.name for plane in jax.profiler.ProfileData.from_file(
+            str(xplane)).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events)
+    for name in ("arbiter.split", "serve.execute", "serve.stack",
+                 "serve.plan", "serve.dispatch", "serve.results",
+                 "replan", "plan_network"):
+        print(f"  {name:15s} {names[name]:4d} spans")
+    print(f"  -> {timeline} (load at ui.perfetto.dev)")
     print("  event log (always on, even with the tracer off):")
     for ev in EVENTS.recent(4):
         print(f"    {ev['kind']}: "

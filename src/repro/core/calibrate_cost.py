@@ -235,8 +235,7 @@ class CalibrationTable:
         """
         if min_samples is not None:
             self.min_samples = int(min_samples)
-        with (TRACER.span("calibration.fit", "calibrate",
-                          {"samples": len(self.samples)})
+        with (TRACER.span("calibration.fit", samples=len(self.samples))
               if TRACER.enabled else NOOP_SPAN):
             by_member: Dict[str, List[Tuple[float, float, float,
                                             float]]] = {}
@@ -448,9 +447,8 @@ def measure_planned_site(site, *, warmup: int = 1,
     standalone on synthetic operands of the site's declared shapes, via
     the exact dispatch the execution layer uses (quantized wrappers for
     lowered rungs)."""
-    with (TRACER.span("calibration.measure", "calibrate",
-                      {"site": site.spec.name, "member": site.ip.name,
-                       "bits": site.precision_bits})
+    with (TRACER.span("calibration.measure", site=site.spec.name,
+                      member=site.ip.name, bits=site.precision_bits)
           if TRACER.enabled else NOOP_SPAN):
         return timeit_us(
             _site_runner(site, seed=seed),

@@ -356,7 +356,7 @@ def _select_site(spec: SiteSpec, budget: ResourceBudget, calibration=None,
     widths = spec.widths()
     if not fam.quantizable:
         widths = widths[:1]
-    span = (TRACER.span("select", "plan", {"site": spec.name})
+    span = (TRACER.span("select", site=spec.name)
             if TRACER.enabled else NOOP_SPAN)
     err = None
     with span:
@@ -704,9 +704,8 @@ def plan_network(specs: Iterable[SiteSpec],
         STATS.plan_hits += 1
         return cached
     STATS.plan_misses += 1
-    with (TRACER.span("plan_network", "plan",
-                      {"sites": len(key[0]), "fuse": fuse,
-                       "mesh_devices": mesh.devices if mesh else 1})
+    with (TRACER.span("plan_network", sites=len(key[0]), fuse=fuse,
+                      mesh_devices=mesh.devices if mesh else 1)
           if TRACER.enabled else NOOP_SPAN):
         plan = _plan_uncached(key[0], budget, fuse=fuse,
                               calibration=calibration, mesh=mesh)
@@ -783,7 +782,7 @@ def replan(specs: Iterable[SiteSpec],
     STATS.plan_misses += 1
     fell_cold = False
     try:
-        with (TRACER.span("replan", "plan", {"sites": len(eff)})
+        with (TRACER.span("replan", sites=len(eff))
               if TRACER.enabled else NOOP_SPAN):
             plan = _assign_with_repair(
                 eff, budget, shares, calibration=calibration,

@@ -295,8 +295,8 @@ def plan_shard_decisions(specs: Sequence[SiteSpec], budget: ResourceBudget,
     site that had a split available and stayed replicated.
     """
     specs = tuple(specs)
-    with (TRACER.span("plan_shard_decisions", "shard",
-                      {"sites": len(specs), "devices": mesh.devices})
+    with (TRACER.span("plan_shard_decisions", sites=len(specs),
+                      devices=mesh.devices)
           if TRACER.enabled else NOOP_SPAN):
         return _plan_shard_decisions(specs, budget, mesh, select,
                                      calibration, events)

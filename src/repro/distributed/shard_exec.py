@@ -180,10 +180,9 @@ def apply_plan_sharded(plan: NetworkPlan, x: jnp.ndarray,
 
     fn = shard_map(device_fn, mesh=mesh, in_specs=(P(), P()),
                    out_specs=P(), check_vma=False)
-    with (TRACER.span("shard_exec.apply", "collective",
-                      {"devices": d, "axis": axis,
-                       "comm_cycles": sum(s.footprint.comm_cycles
-                                          for s in plan.sites)})
+    with (TRACER.span("shard_exec.apply", devices=d, axis=axis,
+                      comm_cycles=sum(s.footprint.comm_cycles
+                                      for s in plan.sites))
           if TRACER.enabled else NOOP_SPAN):
         y = fn(x, dict(weights))
     if INJECTOR.enabled:

@@ -5,9 +5,10 @@ The planner picks members, the arbiter moves grants, the mesh pass
 prices collectives, and the calibration table claims to predict
 wall-clock — this package is how an operator *sees* any of it:
 
-* ``obs.trace``   — low-overhead span tracer (Chrome trace-event JSON,
-  Perfetto-loadable) + the always-on bounded event log for operator
-  events (watchdog firings, plan-cache evictions, drift trips).
+* ``obs.trace``   — span tracer on the JAX profiler's timeline (on
+  while a profiler session records), the always-on compile counter, and
+  the always-on bounded event log for operator events (watchdog
+  firings, plan-cache evictions, drift trips).
 * ``obs.audit``   — the plan decision audit: per-site candidate sets
   with concrete rejection reasons, surfaced via
   ``NetworkPlan.explain()``.
@@ -29,8 +30,8 @@ from repro.obs.audit import (CandidateRecord, PlanAudit, SiteAudit,
 from repro.obs.drift import DriftMonitor, DriftReport, mis_scaled_table
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                percentile, system_metrics)
-from repro.obs.trace import (EVENTS, NOOP_SPAN, TRACER, EventLog, SpanTracer,
-                             log_event)
+from repro.obs.trace import (COMPILES, EVENTS, NOOP_SPAN, TRACER,
+                             CompileCounter, EventLog, SpanTracer, log_event)
 
 __all__ = [
     "CandidateRecord", "PlanAudit", "SiteAudit", "SiteAuditRecorder",
@@ -38,5 +39,6 @@ __all__ = [
     "DriftMonitor", "DriftReport", "mis_scaled_table",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "percentile",
     "system_metrics",
-    "EVENTS", "NOOP_SPAN", "TRACER", "EventLog", "SpanTracer", "log_event",
+    "COMPILES", "EVENTS", "NOOP_SPAN", "TRACER", "CompileCounter", "EventLog",
+    "SpanTracer", "log_event",
 ]

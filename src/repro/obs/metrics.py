@@ -2,8 +2,8 @@
 
 The system already counts plenty (``core.plan.PlannerStats``, the LRU
 cache's ``plan_cache_stats``, ``BudgetArbiter.rebalances``, per-tenant
-``TenantTelemetry``, the tracer/event-log buffers) but each behind its
-own ad-hoc dict.  This module unifies them:
+``TenantTelemetry``, the event log, JAX's tracings and compiles) but
+each behind its own ad-hoc dict.  This module unifies them:
 
 * ``Counter`` / ``Gauge`` / ``Histogram`` — the three metric kinds,
   labeled, registered in a ``MetricsRegistry``.
@@ -12,8 +12,8 @@ own ad-hoc dict.  This module unifies them:
   (``# HELP`` / ``# TYPE``; histograms render summary-style with
   quantile labels, ``_sum`` and ``_count``).
 * ``system_metrics(server=None)`` — the collector: walks the planner
-  stats, plan cache, event log, tracer, and (when given a server) the
-  arbiter + per-tenant telemetry into a fresh registry.
+  stats, plan cache, event log, compile counter, and (when given a
+  server) the arbiter + per-tenant telemetry into a fresh registry.
 * ``percentile(values, q)`` — THE percentile estimator.
   ``TenantTelemetry.latency_percentile`` and ``Histogram.quantile``
   both delegate here, so serving telemetry and metrics exposition can
@@ -213,7 +213,7 @@ def system_metrics(server=None,
                    registry: Optional[MetricsRegistry] = None,
                    scheduler=None) -> MetricsRegistry:
     """Collect the system's scattered stats into one registry: planner
-    counters + plan cache, event log, tracer buffer — and, when given
+    counters + plan cache, event log, JAX compile counts — and, when given
     an ``AdaptiveServer``, its arbiter, queue, and per-tenant telemetry
     (shard degree, comm share, and SLO outcome columns included).
     ``scheduler=`` (an ``SLOScheduler``) adds per-tenant queue-depth
@@ -234,14 +234,16 @@ def system_metrics(server=None,
         reg.counter(f"planner_{field}_total",
                     "planner counter (core.plan.PlannerStats)").inc(value)
 
-    from repro.obs.trace import EVENTS, TRACER
+    from repro.obs.trace import COMPILES, EVENTS
     for kind, n in sorted(EVENTS.counts().items()):
         reg.counter("events_total", "event-log entries in window",
                     kind=kind).inc(n)
-    tstats = TRACER.stats()
-    reg.gauge("tracer_enabled").set(1.0 if tstats["enabled"] else 0.0)
-    reg.gauge("tracer_buffered_events").set(tstats["events"])
-    reg.counter("tracer_dropped_events_total").inc(tstats["dropped"])
+    for fun, n in sorted(COMPILES.counts("jit.trace").items()):
+        reg.counter("jit_traces_total", "JAX tracings to a jaxpr",
+                    fun=fun).inc(n)
+    for fun, n in sorted(COMPILES.counts("jit.compile").items()):
+        reg.counter("jit_compiles_total", "JAX backend compiles",
+                    fun=fun).inc(n)
 
     if server is not None:
         reg.gauge("server_pending_requests",

@@ -1,46 +1,60 @@
-"""Span tracer + event log — the timing half of the observability layer.
+"""Span tracer, compile counter and event log — the timing half of the
+observability layer.
 
-Two instruments with different always-on contracts:
+Three instruments:
 
-* ``SpanTracer`` (singleton ``TRACER``) records *spans* — named,
-  categorized wall-clock intervals — and instant markers, exportable as
-  Chrome trace-event JSON (``export_chrome_trace``) loadable in
-  Perfetto or chrome://tracing.  It is **off by default**, and the
-  disabled path is allocation-free: ``TRACER.span(...)`` is only ever
-  called behind an ``if TRACER.enabled`` guard at hot call sites (the
-  serving loop), with the shared ``NOOP_SPAN`` singleton taken on the
-  else branch — no argument dict, no context-manager object, nothing
-  for the GC.  The idiom::
+* ``SpanTracer`` (singleton ``TRACER``) writes *spans* — named host
+  intervals — and instant markers straight into the JAX profiler's
+  trace (``jax.profiler.TraceAnnotation``).  The profiler is the only
+  backend and its clock is the only clock, so the program's spans line
+  up with the device planes of the same ``.xplane.pb``.  Tracing is on
+  exactly while a profiler session records (``jax.profiler.trace`` or
+  ``start_trace``/``stop_trace``); there is no switch of its own.  Span
+  names are fixed strings; whatever varies goes in the stats (keyword
+  arguments).  The disabled path allocates nothing: hot call sites guard
+  with ``if TRACER.enabled`` and take the shared ``NOOP_SPAN`` singleton
+  on the else branch — no stats dict, no annotation object.  The idiom::
 
-      with (TRACER.span("serve.execute", "serving", {...})
+      with (TRACER.span("serve.execute", tenant=name, batch=n)
             if TRACER.enabled else NOOP_SPAN):
           ...
 
-  costs one attribute read and one branch when tracing is off.
+  costs one ``TraceAnnotation.is_enabled()`` call and one branch when no
+  profiler records.  For a timeline, run the code under
+  ``jax.profiler.trace(dir, create_perfetto_trace=True)``: the file holds
+  these spans beside the device ops.
+
+* ``CompileCounter`` (singleton ``COMPILES``) listens, through
+  ``jax.monitoring``, for JAX's tracing and backend-compile events.  It
+  always counts them by (event, function name), and while the profiler
+  records it marks each one on the timeline as a ``jit.trace`` or
+  ``jit.compile`` instant (stats ``fun``, ``seconds``), so a retrace
+  shows inside the span it fell in.
 
 * ``EventLog`` (singleton ``EVENTS``) is **always on**: a small bounded
   ring of operator-relevant events (watchdog timeouts, plan-cache
   evictions, arbiter rebalances, calibration drift trips) that would
-  otherwise be invisible.  Events mirror into the tracer as instant
-  markers when it is enabled, so a trace shows them on the timeline.
-
-Thread safety: both instruments take a lock per record; spans carry the
-recording thread's id so multi-threaded traces lay out per-thread in
-Perfetto.  Buffers are bounded (drops are counted, never silent).
+  otherwise be invisible.  Events mirror onto the timeline as instants
+  while the profiler records.
 """
 from __future__ import annotations
 
-import json
+import collections
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
-# Bounded buffers: a serving process must not grow without limit just
-# because someone left tracing on.
-TRACE_BUFFER_MAX = 100_000
+import jax
+from jax.profiler import TraceAnnotation
+
 EVENT_LOG_MAX = 1024
 
-_PID = 1    # one process; Chrome's pid slot is a display group here
+# JAX's duration events for one tracing to a jaxpr and one backend
+# compile, and the instant each becomes on the timeline.
+JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
 
 
 class _NoopSpan:
@@ -60,118 +74,64 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
-class _Span:
-    """One live span: records a Chrome 'X' (complete) event on exit."""
-
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
-
-    def __init__(self, tracer: "SpanTracer", name: str, cat: str,
-                 args: Optional[dict]):
-        self._tracer = tracer
-        self.name = name
-        self.cat = cat
-        self.args = args
-        self._t0 = time.perf_counter_ns()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
-        self._tracer._record({
-            "name": self.name,
-            "cat": self.cat or "default",
-            "ph": "X",
-            "ts": self._t0 / 1e3,           # Chrome wants microseconds
-            "dur": (t1 - self._t0) / 1e3,
-            "pid": _PID,
-            "tid": threading.get_ident(),
-            **({"args": self.args} if self.args else {}),
-        })
-        return False
-
-
 class SpanTracer:
-    """Span recorder; see module docstring.  Use the ``TRACER``
-    singleton — one process, one timeline."""
+    """Spans on the profiler's timeline; see module docstring.  Use the
+    ``TRACER`` singleton."""
 
-    def __init__(self, max_events: int = TRACE_BUFFER_MAX):
-        self.enabled = False
-        self.max_events = max_events
-        self.dropped = 0
-        self._events: List[dict] = []
-        self._lock = threading.Lock()
+    __slots__ = ()
 
-    # -- control ------------------------------------------------------------
-    def enable(self) -> "SpanTracer":
-        self.enabled = True
-        return self
+    @property
+    def enabled(self) -> bool:
+        """True while a profiler session records."""
+        return TraceAnnotation.is_enabled()
 
-    def disable(self) -> "SpanTracer":
-        self.enabled = False
-        return self
-
-    def clear(self) -> None:
-        with self._lock:
-            self._events.clear()
-            self.dropped = 0
-
-    # -- recording ----------------------------------------------------------
-    def span(self, name: str, cat: str = "",
-             args: Optional[dict] = None):
-        """A context manager timing one span.  Hot call sites must guard
-        with ``if TRACER.enabled`` and take ``NOOP_SPAN`` otherwise (the
-        allocation-free contract); calling this while disabled still
-        returns ``NOOP_SPAN`` so un-guarded cold sites stay correct."""
-        if not self.enabled:
+    def span(self, name: str, /, **stats):
+        """A context manager for one span named ``name`` with ``stats``.
+        Hot call sites guard with ``if TRACER.enabled`` and take
+        ``NOOP_SPAN`` otherwise; while no profiler records this returns
+        ``NOOP_SPAN`` too, so un-guarded cold sites stay correct."""
+        if not TraceAnnotation.is_enabled():
             return NOOP_SPAN
-        return _Span(self, name, cat, args)
+        return TraceAnnotation(name, **stats)
 
-    def instant(self, name: str, cat: str = "",
-                args: Optional[dict] = None) -> None:
-        """A zero-duration marker (Chrome 'i' event)."""
-        if not self.enabled:
-            return
-        self._record({
-            "name": name,
-            "cat": cat or "default",
-            "ph": "i",
-            "s": "t",                       # thread-scoped marker
-            "ts": time.perf_counter_ns() / 1e3,
-            "pid": _PID,
-            "tid": threading.get_ident(),
-            **({"args": args} if args else {}),
-        })
-
-    def _record(self, event: dict) -> None:
-        with self._lock:
-            if len(self._events) >= self.max_events:
-                self.dropped += 1
-                return
-            self._events.append(event)
-
-    # -- export -------------------------------------------------------------
-    def events(self) -> List[dict]:
-        with self._lock:
-            return list(self._events)
-
-    def export_chrome_trace(self, indent: Optional[int] = None) -> str:
-        """The buffered spans as Chrome trace-event JSON (the
-        ``traceEvents`` array-of-objects form Perfetto and
-        chrome://tracing both load)."""
-        return json.dumps({
-            "traceEvents": self.events(),
-            "displayTimeUnit": "ms",
-            "otherData": {"dropped_events": self.dropped},
-        }, indent=indent)
-
-    def stats(self) -> dict:
-        with self._lock:
-            return {"enabled": self.enabled, "events": len(self._events),
-                    "dropped": self.dropped, "capacity": self.max_events}
+    def instant(self, name: str, /, **stats) -> None:
+        """A zero-length marker."""
+        if TraceAnnotation.is_enabled():
+            with TraceAnnotation(name, **stats):
+                pass
 
 
 TRACER = SpanTracer()
+
+
+class CompileCounter:
+    """Counts JAX's tracings and backend compiles; see module
+    docstring.  Its ``__call__`` is a ``jax.monitoring`` duration
+    listener."""
+
+    def __init__(self):
+        self._counts: Dict[Tuple[str, str], int] = collections.Counter()
+        self._lock = threading.Lock()
+
+    def __call__(self, event: str, duration_secs: float, **kwargs) -> None:
+        name = JIT_EVENTS.get(event)
+        if name is None:
+            return
+        fun = str(kwargs.get("fun_name", "?"))
+        with self._lock:
+            self._counts[(name, fun)] += 1
+        TRACER.instant(name, fun=fun, seconds=duration_secs)
+
+    def counts(self, name: str) -> Dict[str, int]:
+        """Function name -> count of one instant kind (``jit.trace`` or
+        ``jit.compile``) since the process started."""
+        with self._lock:
+            return {fun: n for (kind, fun), n in self._counts.items()
+                    if kind == name}
+
+
+COMPILES = CompileCounter()
+jax.monitoring.register_event_duration_secs_listener(COMPILES)
 
 
 class EventLog:
@@ -186,16 +146,15 @@ class EventLog:
     def log(self, kind: str, **fields) -> None:
         """Record one event.  ``kind`` is a dotted taxonomy name
         (``"watchdog.timeout"``, ``"plan_cache.evict"``); fields are
-        free-form JSON-able payload.  Mirrors into the tracer as an
-        instant marker when tracing is on."""
+        free-form JSON-able payload.  Mirrors onto the profiler's
+        timeline as an instant while a profiler session records."""
         event = {"kind": kind, "t": time.time(), **fields}
         with self._lock:
             self.total += 1
             self._events.append(event)
             if len(self._events) > self.max_events:
                 del self._events[:len(self._events) - self.max_events]
-        if TRACER.enabled:
-            TRACER.instant(kind, "events", fields or None)
+        TRACER.instant(kind, **fields)
 
     def recent(self, n: int = 50, kind: Optional[str] = None) -> List[dict]:
         with self._lock:

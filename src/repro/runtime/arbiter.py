@@ -36,7 +36,7 @@ from typing import Dict, Optional, Tuple
 
 from repro.core.resources import MeshSpec, ResourceBudget
 from repro.core.shard import degree_ladder
-from repro.obs.trace import NOOP_SPAN, TRACER, log_event
+from repro.obs.trace import log_event
 
 POLICIES = ("demand", "static")
 
@@ -213,12 +213,6 @@ class BudgetArbiter:
         """
         if not self._floors:
             return {}
-        with (TRACER.span("arbiter.split", "arbiter",
-                          {"tenants": len(self._floors)})
-              if TRACER.enabled else NOOP_SPAN):
-            return self._split()
-
-    def _split(self) -> Dict[str, TenantShare]:
         a = self.demand_alpha
         for name, pend in self._pending.items():
             self._demand[name] = (1 - a) * self._demand[name] + a * pend

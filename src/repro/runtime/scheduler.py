@@ -50,7 +50,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.obs.trace import log_event
+from repro.obs.trace import NOOP_SPAN, TRACER, log_event
 from repro.runtime.batching import Request
 from repro.runtime.server import AdaptiveServer, Completion
 
@@ -173,10 +173,12 @@ class SLOScheduler:
         self._order += 1
         return rid
 
-    def _admit_due(self) -> None:
+    def _admit_due(self) -> int:
         """Admit every arrival due at the dispatch frontier: stamp its
         wall deadline, enforce the tenant's queue-depth cap, join (or
-        open) its not-yet-launched bucket."""
+        open) its not-yet-launched bucket.  Returns how many joined a
+        bucket."""
+        admitted = 0
         while self._arrivals and self._arrivals[0][0] <= self.now:
             arrival, _, rid, name, x = heapq.heappop(self._arrivals)
             tenant = self.server.tenants[name]
@@ -201,6 +203,8 @@ class SLOScheduler:
             bucket.items.append(adm)
             self.server.arbiter.observe(name, tenant.unit_cost)
             self._dirty = True
+            admitted += 1
+        return admitted
 
     def queue_depth(self, name: str) -> int:
         """Admitted-but-unlaunched requests of one tenant (the number
@@ -214,12 +218,14 @@ class SLOScheduler:
                 + len(self._arrivals))
 
     # -- dispatch -----------------------------------------------------------
-    def _shed_hopeless(self) -> None:
+    def _shed_hopeless(self) -> int:
         """Drop queued requests that can no longer meet their deadline
         (wall clock past ``deadline_wall - shed_margin_s``).  Every shed
         is a recorded miss; executing it anyway would only push the
-        bucket's *other* deadlines past hope too."""
+        bucket's *other* deadlines past hope too.  Returns how many were
+        dropped."""
         w = self.wall()
+        shed = 0
         for key in list(self._buckets):
             bucket = self._buckets[key]
             keep, drop = [], []
@@ -234,6 +240,7 @@ class SLOScheduler:
             tenant = self.server.tenants[key[0]]
             tenant.telemetry.record_shed(len(drop))
             self.sheds += len(drop)
+            shed += len(drop)
             self.server.arbiter.record_outcome(
                 key[0], served=len(drop), missed=len(drop))
             self._dirty = True
@@ -243,6 +250,7 @@ class SLOScheduler:
                           late_s=w - adm.deadline_wall)
             if not bucket.items:
                 del self._buckets[key]
+        return shed
 
     def _launchable(self) -> List[Tuple]:
         """Bucket keys whose tenant lane is free at the frontier."""
@@ -292,25 +300,44 @@ class SLOScheduler:
                 self._dirty = True       # let split() re-settle later
         return chosen
 
-    def _launch(self, key: Tuple) -> List[Completion]:
+    def _launch(self, key: Tuple, span=NOOP_SPAN) -> List[Completion]:
         """Execute up to ``max_batch`` earliest-deadline requests of one
         bucket and judge them on the wall clock.  The batch's tightest
         remaining deadline budget rides along so a guarded execution's
         retries are charged against it (``runtime/guards.py``); a
         guard-failed completion (``ok=False``) counts as a miss for the
-        arbiter's SLO pressure."""
+        arbiter's SLO pressure.  ``span`` (the open ``sched.launch``
+        span) is given the batch's tenant, size and wall wait."""
+        launch = self.launches
         bucket = self._buckets[key]
         bucket.items.sort(key=lambda a: (a.deadline_wall, a.req.rid))
         take = bucket.items[:self.server.max_batch]
         bucket.items = bucket.items[self.server.max_batch:]
         if not bucket.items:
             del self._buckets[key]
-        budget_s = min(a.deadline_wall for a in take) - self.wall()
+        now = self.wall()
+        budget_s = min(a.deadline_wall for a in take) - now
+        if span is not NOOP_SPAN:
+            oldest = min(a.admitted_wall for a in take)
+            span.set_metadata(tenant=key[0], batch=len(take),
+                              wait_ms=(now - oldest) * 1e3)
         comps = self.server._execute([a.req for a in take],
-                                     deadline_budget_s=max(budget_s, 0.0))
+                                     deadline_budget_s=max(budget_s, 0.0),
+                                     launch=launch)
         # JAX returns once the kernels are enqueued: stamp completion
         # only after the outputs exist on the device
-        jax.block_until_ready([c.result for c in comps if c.ok])
+        with (TRACER.span("sched.block", launch=launch)
+              if TRACER.enabled else NOOP_SPAN):
+            jax.block_until_ready([c.result for c in comps if c.ok])
+        with (TRACER.span("sched.judge", launch=launch)
+              if TRACER.enabled else NOOP_SPAN):
+            self._judge(key[0], take, comps)
+        return comps
+
+    def _judge(self, name: str, take: List[_Admitted],
+               comps: List[Completion]) -> None:
+        """Verdicts on the wall clock for one launched batch, folded into
+        the tenant's telemetry and the arbiter's SLO pressure."""
         w = self.wall()
         walls = [w - a.admitted_wall for a in take]
         missed = failed = 0
@@ -323,7 +350,6 @@ class SLOScheduler:
                 self.outcomes[adm.req.rid] = "miss"
             else:
                 self.outcomes[adm.req.rid] = "ok"
-        name = key[0]
         self.server.tenants[name].telemetry.record_slo_batch(walls, missed)
         self.server.arbiter.record_outcome(name, served=len(take),
                                            missed=missed + failed)
@@ -332,7 +358,6 @@ class SLOScheduler:
         self.launches += 1
         if self.recovery is not None:
             self.recovery.beat()
-        return comps
 
     def run(self, max_launches: int = 100_000) -> List[Completion]:
         """Drive the loop until every queued and deferred request has a
@@ -340,17 +365,25 @@ class SLOScheduler:
         completions in launch order."""
         completions: List[Completion] = []
         while self.pending() and self.launches < max_launches:
-            self._admit_due()
-            self._shed_hopeless()
-            launchable = self._launchable()
+            with (TRACER.span("sched.admit", launch=self.launches)
+                  if TRACER.enabled else NOOP_SPAN) as span:
+                admitted = self._admit_due()
+                shed = self._shed_hopeless()
+                launchable = self._launchable()
+                moved = bool(launchable) or self._advance()
+                if span is not NOOP_SPAN:
+                    span.set_metadata(admitted=admitted, shed=shed)
+            if not moved:
+                break
             if not launchable:
-                if not self._advance():
-                    break
                 continue
             if self._dirty:
-                self.server._apply_shares(self.server.arbiter.split())
+                self.server._rebalance(self.launches)
                 self._dirty = False
-            completions.extend(self._launch(self._choose(launchable)))
+            with (TRACER.span("sched.launch", launch=self.launches)
+                  if TRACER.enabled else NOOP_SPAN) as span:
+                completions.extend(
+                    self._launch(self._choose(launchable), span))
         if completions:
             self.server.clock = max(self.server.clock, self.now,
                                     max(c.finished for c in completions))

@@ -244,7 +244,7 @@ class AdaptiveServer:
         """
         if not self._queue:
             return []
-        self._apply_shares(self.arbiter.split())
+        self._rebalance()
         completions: List[Completion] = []
         for key in self._queue.keys():
             while True:
@@ -256,6 +256,15 @@ class AdaptiveServer:
             self.clock = max(self.clock,
                              max(c.finished for c in completions))
         return completions
+
+    def _rebalance(self, launch: int = -1) -> None:
+        """One arbitration round: ``BudgetArbiter.split`` and its grants
+        adopted.  ``launch`` is the SLO scheduler's launch count (-1
+        outside it), a stat of the span."""
+        with (TRACER.span("arbiter.split", launch=launch,
+                          tenants=len(self.tenants))
+              if TRACER.enabled else NOOP_SPAN):
+            self._apply_shares(self.arbiter.split())
 
     def _apply_shares(self, shares: Dict[str, TenantShare]) -> None:
         """Adopt one arbitration round's grants.  A moved grant changes
@@ -280,17 +289,19 @@ class AdaptiveServer:
         return out
 
     def _execute(self, batch: List[Request], *,
-                 deadline_budget_s: Optional[float] = None
-                 ) -> List[Completion]:
-        # Tracing contract: the disabled path costs one attribute read
-        # and one branch per span site — no argument dicts, no span
-        # objects (NOOP_SPAN is the shared singleton).
-        with (TRACER.span("serve.execute", "serving",
-                          {"tenant": batch[0].tenant,
-                           "batch": len(batch)})
+                 deadline_budget_s: Optional[float] = None,
+                 launch: int = -1) -> List[Completion]:
+        """Run one batch.  ``launch`` is the SLO scheduler's launch
+        count (-1 outside it); every span of the batch carries it."""
+        # Tracing contract: with no profiler recording, each span site
+        # costs one is_enabled() call and one branch — no stats dicts,
+        # no span objects (NOOP_SPAN is the shared singleton).
+        with (TRACER.span("serve.execute", launch=launch,
+                          tenant=batch[0].tenant, batch=len(batch))
               if TRACER.enabled else NOOP_SPAN):
             return self._execute_batch(batch,
-                                       deadline_budget_s=deadline_budget_s)
+                                       deadline_budget_s=deadline_budget_s,
+                                       launch=launch)
 
     def _tenant_budget(self, tenant: Tenant):
         if self.mesh is not None:
@@ -344,7 +355,8 @@ class AdaptiveServer:
         return self._plan(tenant, (batch,) + tenant.input_shape,
                           jnp.dtype("float32"), tenant.ladder)[3]
 
-    def _attempt(self, tenant: Tenant, xb, *, retry_f32: bool = False):
+    def _attempt(self, tenant: Tenant, xb, *, retry_f32: bool = False,
+                 launch: int = -1):
         """One execution attempt: route injected faults, (re)plan under
         the tenant's *current* slice — a degraded mesh re-plans here —
         run the kernels, screen hooks applied by the caller.  Returns
@@ -353,8 +365,10 @@ class AdaptiveServer:
         if INJECTOR.enabled:
             self._route_execute_faults(tenant)
         ladder = () if retry_f32 else tenant.ladder
-        specs, slice_budget, tenant_mesh, plan = self._plan(
-            tenant, xb.shape, xb.dtype, ladder)
+        with (TRACER.span("serve.plan", launch=launch)
+              if TRACER.enabled else NOOP_SPAN):
+            specs, slice_budget, tenant_mesh, plan = self._plan(
+                tenant, xb.shape, xb.dtype, ladder)
         if INJECTOR.enabled and tenant_mesh is not None:
             INJECTOR.check_devices(*self.arbiter.device_slice(tenant.name))
         tile_overrides = None
@@ -369,10 +383,8 @@ class AdaptiveServer:
                 self._tile_cache[tkey] = tile_overrides
         quant_report = {} if (ladder and tenant.measure_quant) else None
         sharded = self._shardable(plan, xb)
-        with (TRACER.span("kernel", "kernel",
-                          {"tenant": tenant.name,
-                           "launches": plan.total_launches,
-                           "sharded": sharded})
+        with (TRACER.span("serve.dispatch", launch=launch,
+                          launches=plan.total_launches, sharded=sharded)
               if TRACER.enabled else NOOP_SPAN):
             if sharded:
                 y = self._run_frontend_sharded(
@@ -396,16 +408,19 @@ class AdaptiveServer:
         return y, plan, quant_err
 
     def _execute_batch(self, batch: List[Request], *,
-                       deadline_budget_s: Optional[float] = None
-                       ) -> List[Completion]:
+                       deadline_budget_s: Optional[float] = None,
+                       launch: int = -1) -> List[Completion]:
         tenant = self.tenants[batch[0].tenant]
-        xb = jnp.stack([r.x for r in batch])
+        with (TRACER.span("serve.stack", launch=launch, batch=len(batch))
+              if TRACER.enabled else NOOP_SPAN):
+            xb = jnp.stack([r.x for r in batch])
         hits0, misses0 = STATS.plan_hits, STATS.plan_misses
         policy = self._guards.get(tenant.name)
         out: Dict[str, Any] = {}
 
         def attempt(retry_f32: bool = False):
-            y, plan, qerr = self._attempt(tenant, xb, retry_f32=retry_f32)
+            y, plan, qerr = self._attempt(tenant, xb, retry_f32=retry_f32,
+                                          launch=launch)
             out["plan"], out["quant_err"] = plan, qerr
             return y
 
@@ -430,29 +445,25 @@ class AdaptiveServer:
                                arrival=r.arrival, finished=start,
                                batch_size=len(batch), ok=False)
                     for r in batch]
-        plan, quant_err = out["plan"], out["quant_err"]
-        start = max(tenant.lane_free, max(r.arrival for r in batch))
-        if TRACER.enabled:
-            TRACER.instant(
-                "batch.queue_wait", "serving",
-                {"tenant": tenant.name,
-                 "max_wait_cycles":
-                     start - min(r.arrival for r in batch)})
-        service = plan.calibrated_cycles(self.calibration)
-        if INJECTOR.enabled:
-            service = INJECTOR.scale_latency(service, tenant.name)
-        finish = start + service
-        tenant.lane_free = finish
-        latencies = [finish - r.arrival for r in batch]
-        tenant.telemetry.record_batch(
-            len(batch), latencies, plan,
-            cache_hits=STATS.plan_hits - hits0,
-            cache_misses=STATS.plan_misses - misses0,
-            quant_err=quant_err)
-        return [Completion(rid=r.rid, tenant=r.tenant, result=y[i],
-                           arrival=r.arrival, finished=finish,
-                           batch_size=len(batch))
-                for i, r in enumerate(batch)]
+        with (TRACER.span("serve.results", launch=launch, batch=len(batch))
+              if TRACER.enabled else NOOP_SPAN):
+            plan, quant_err = out["plan"], out["quant_err"]
+            start = max(tenant.lane_free, max(r.arrival for r in batch))
+            service = plan.calibrated_cycles(self.calibration)
+            if INJECTOR.enabled:
+                service = INJECTOR.scale_latency(service, tenant.name)
+            finish = start + service
+            tenant.lane_free = finish
+            latencies = [finish - r.arrival for r in batch]
+            tenant.telemetry.record_batch(
+                len(batch), latencies, plan,
+                cache_hits=STATS.plan_hits - hits0,
+                cache_misses=STATS.plan_misses - misses0,
+                quant_err=quant_err)
+            return [Completion(rid=r.rid, tenant=r.tenant, result=y[i],
+                               arrival=r.arrival, finished=finish,
+                               batch_size=len(batch))
+                    for i, r in enumerate(batch)]
 
     @staticmethod
     def _shardable(plan, xb) -> bool:
@@ -595,7 +606,7 @@ class AdaptiveServer:
     def metrics(self, registry=None):
         """This server's state folded into a ``MetricsRegistry``
         (``repro.obs.metrics``): planner/cache counters, event log,
-        tracer stats, arbiter rebalances, and per-tenant telemetry
+        JAX compile counts, arbiter rebalances, and per-tenant telemetry
         including shard degree and comm-cycles share.  Render with
         ``.render()`` (Prometheus text) or ``.snapshot()``."""
         from repro.obs.metrics import system_metrics

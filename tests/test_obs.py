@@ -1,16 +1,23 @@
 """Cross-layer observability (src/repro/obs): span tracer contracts
-(thread safety, allocation-free disabled path, Chrome trace-event JSON
-schema), the always-on event log and its runtime routing (watchdog
+(spans and instants on the profiler's timeline, one line per thread,
+on exactly while a profiler session records, allocation-free disabled
+path), the launch path's spans under the SLO scheduler, the compile
+counter, the always-on event log and its runtime routing (watchdog
 timeouts, plan-cache evictions, arbiter rebalances), plan decision
 audits with concrete rejection reasons, the metrics registry and its
 Prometheus exposition, telemetry shard columns, and the calibration
 drift monitor's flag/recalibrate loop."""
+import collections
+import glob
+import gzip
 import json
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.core import plan as plan_mod
 from repro.core.calibrate_cost import CalibrationTable
@@ -20,25 +27,24 @@ from repro.core.plan import (NetworkPlan, clear_plan_cache, plan_network,
 from repro.core.resources import Footprint, ResourceBudget, hbm_cycles
 from repro.models.blocks import cnn_block_site_specs
 from repro.models.frontends import init_cnn_frontend
-from repro.obs import (EVENTS, NOOP_SPAN, TRACER, DriftMonitor,
+from repro.obs import (COMPILES, EVENTS, NOOP_SPAN, TRACER, DriftMonitor,
                        MetricsRegistry, PlanAudit, log_event,
                        mis_scaled_table, percentile, system_metrics,
                        unfit_reason)
-from repro.runtime import AdaptiveServer
+from repro.runtime import AdaptiveServer, SLOScheduler, SLOSpec
 from repro.runtime.fault_tolerance import Watchdog
 from repro.runtime.telemetry import TenantTelemetry
 
 
 @pytest.fixture(autouse=True)
 def _clean_obs():
-    """Every test starts and ends with the tracer off and both global
-    buffers empty — the singletons must not leak across tests."""
-    TRACER.disable()
-    TRACER.clear()
+    """Every test starts and ends with no profiler session (so the
+    tracer off) and the event log empty — the singletons must not leak
+    across tests."""
+    assert not TRACER.enabled
     EVENTS.clear()
     yield
-    TRACER.disable()
-    TRACER.clear()
+    assert not TRACER.enabled
     EVENTS.clear()
 
 
@@ -48,6 +54,42 @@ def _block_specs(site="obs"):
     return tuple(specs)
 
 
+HostEvent = collections.namedtuple(
+    "HostEvent", "line name start_ns end_ns stats")
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a CPU profiler session writing into ``tmp_path``;
+    return the host events of the trace (``line`` tells threads apart)."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                out.append(HostEvent((plane.name, i), ev.name, ev.start_ns,
+                                     ev.start_ns + ev.duration_ns,
+                                     {k: v for k, v in ev.stats}))
+    return out
+
+
+def _named(events, name):
+    return [e for e in events if e.name == name]
+
+
+def _within(inner, outer):
+    return (outer.line == inner.line and outer.start_ns <= inner.start_ns
+            and inner.end_ns <= outer.end_ns)
+
+
 # --------------------------------------------------------------------------
 # Span tracer
 # --------------------------------------------------------------------------
@@ -55,81 +97,156 @@ def test_tracer_disabled_path_is_noop_singleton():
     assert not TRACER.enabled
     # The disabled path hands back the one shared object — nothing to
     # allocate, nothing recorded.
-    assert TRACER.span("anything", "cat", {"k": 1}) is NOOP_SPAN
-    with TRACER.span("x"):
-        pass
-    TRACER.instant("marker")
-    assert TRACER.events() == []
-    assert TRACER.stats()["events"] == 0
+    assert TRACER.span("anything", k=1) is NOOP_SPAN
+    with TRACER.span("x") as s:
+        assert s is NOOP_SPAN
+    assert TRACER.instant("marker") is None
 
 
-def test_tracer_records_spans_and_instants():
-    TRACER.enable()
-    with TRACER.span("work", "test", {"n": 3}):
-        TRACER.instant("tick", "test")
-    TRACER.disable()
-    events = TRACER.events()
-    assert [e["ph"] for e in events] == ["i", "X"]  # span closes after
-    span = events[1]
-    assert span["name"] == "work" and span["cat"] == "test"
-    assert span["dur"] >= 0.0
-    assert span["args"] == {"n": 3}
-    assert span["tid"] == threading.get_ident()
+def test_tracer_records_spans_and_instants(tmp_path):
+    def work():
+        with TRACER.span("work", n=3, who="test"):
+            TRACER.instant("tick", k=1.5)
+    events = _profiled(tmp_path, work)
+    (span,) = _named(events, "work")
+    (tick,) = _named(events, "tick")
+    assert span.stats == {"n": 3, "who": "test"}
+    assert tick.stats == {"k": 1.5}
+    assert _within(tick, span)
+    assert tick.end_ns - tick.start_ns < span.end_ns - span.start_ns
 
 
-def test_tracer_thread_safety():
-    TRACER.enable()
-    # The barrier holds all 8 threads alive at once: thread idents stay
-    # distinct (Python reuses idents of finished threads).
+def test_tracer_thread_safety(tmp_path):
+    # The barrier holds all 8 threads alive at once, so each gets a
+    # line of its own on the timeline.
     barrier = threading.Barrier(8)
 
     def worker():
         barrier.wait()
-        for _ in range(200):
-            with TRACER.span("w", "threads"):
+        for _ in range(50):
+            with TRACER.span("w", worker=1):
                 pass
 
-    threads = [threading.Thread(target=worker) for _ in range(8)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    TRACER.disable()
-    events = TRACER.events()
-    assert len(events) == 8 * 200
-    assert len({e["tid"] for e in events}) == 8
-    json.loads(TRACER.export_chrome_trace())    # buffer survived the race
+    def run():
+        threads = [threading.Thread(target=worker) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+
+    spans = _named(_profiled(tmp_path, run), "w")
+    assert len(spans) == 8 * 50
+    assert len({s.line for s in spans}) == 8
 
 
-def test_chrome_trace_export_schema():
-    TRACER.enable()
-    with TRACER.span("a", "plan"):
-        pass
-    TRACER.instant("b", "events", {"x": 1})
-    TRACER.disable()
-    doc = json.loads(TRACER.export_chrome_trace())
-    assert doc["displayTimeUnit"] == "ms"
-    assert isinstance(doc["traceEvents"], list) and doc["traceEvents"]
-    for ev in doc["traceEvents"]:
-        assert ev["ph"] in ("X", "i")
-        assert isinstance(ev["name"], str) and ev["name"]
-        assert isinstance(ev["ts"], float) and ev["ts"] >= 0
-        assert isinstance(ev["pid"], int) and isinstance(ev["tid"], int)
-        if ev["ph"] == "X":
-            assert ev["dur"] >= 0.0
+def test_tracer_enabled_follows_the_profiler_session(tmp_path):
+    assert not TRACER.enabled
+    seen = []
+    _profiled(tmp_path, lambda: seen.append(TRACER.enabled))
+    assert seen == [True]
+    assert not TRACER.enabled
+    assert TRACER.span("after") is NOOP_SPAN
 
 
-def test_tracer_buffer_bounds_and_counts_drops():
-    from repro.obs.trace import SpanTracer
-    t = SpanTracer(max_events=3)
-    t.enable()
-    for _ in range(5):
-        with t.span("s"):
-            pass
-    assert t.stats()["events"] == 3
-    assert t.stats()["dropped"] == 2
-    doc = json.loads(t.export_chrome_trace())
-    assert doc["otherData"]["dropped_events"] == 2
+def test_profiler_trace_writes_spans_to_a_perfetto_timeline(tmp_path):
+    # What an operator runs in place of an exporter of the tracer's own.
+    with jax.profiler.trace(str(tmp_path), create_perfetto_trace=True):
+        with TRACER.span("timeline.span", n=2):
+            jnp.ones(8).block_until_ready()
+    (path,) = glob.glob(f"{tmp_path}/**/perfetto_trace.json.gz",
+                        recursive=True)
+    with gzip.open(path) as f:
+        doc = json.load(f)
+    names = {e.get("name") for e in doc["traceEvents"]}
+    assert "timeline.span" in names
+
+
+def test_event_log_mirrors_into_enabled_tracer(tmp_path):
+    EVENTS.log("test.quiet", value=1)          # no session: log only
+    events = _profiled(tmp_path,
+                       lambda: EVENTS.log("test.kind", value=7))
+    (ev,) = _named(events, "test.kind")
+    assert ev.stats == {"value": 7}
+    assert not _named(events, "test.quiet")
+    assert [e["kind"] for e in EVENTS.recent()] == ["test.quiet",
+                                                    "test.kind"]
+
+
+# The launch path's spans: name -> the span it nests in (None: none of
+# the program's).
+LAUNCH_SPANS = {
+    "sched.admit": None, "arbiter.split": None, "sched.launch": None,
+    "serve.execute": "sched.launch", "serve.stack": "serve.execute",
+    "serve.plan": "serve.execute", "serve.dispatch": "serve.execute",
+    "serve.results": "serve.execute", "sched.block": "sched.launch",
+    "sched.judge": "sched.launch",
+}
+
+
+def test_served_launch_writes_every_span_nested(tmp_path):
+    clear_plan_cache()
+    srv = AdaptiveServer(ResourceBudget(vpu_ops_budget=15_000_000),
+                         max_batch=4)
+    sched = SLOScheduler(srv)
+    sched.register("t", init_cnn_frontend(jax.random.PRNGKey(0),
+                                          channels=(6, 12), d_model=16),
+                   (12, 12, 6), slo=SLOSpec(deadline_s=60.0))
+    rng = np.random.default_rng(0)
+    frames = rng.normal(size=(8, 12, 12, 6)).astype(np.float32)
+    sched.submit("t", frames[:4])
+    sched.run()                                    # warm: launch 0
+    sched.submit("t", frames[4:])
+    events = _profiled(tmp_path, sched.run)        # launch 1
+    assert sched.launches == 2
+    for name, parent in LAUNCH_SPANS.items():
+        got = _named(events, name)
+        assert got, f"no {name} span"
+        for e in got:
+            assert e.stats["launch"] == 1, (name, e.stats)
+            if parent is not None:
+                assert any(_within(e, p) for p in _named(events, parent)), \
+                    f"{name} is not inside {parent}"
+    (launch,) = _named(events, "sched.launch")
+    assert launch.stats["tenant"] == "t" and launch.stats["batch"] == 4
+    assert launch.stats["wait_ms"] >= 0.0
+    assert sum(e.stats["admitted"] for e in _named(events, "sched.admit")) \
+        == 4
+    (execute,) = _named(events, "serve.execute")
+    (block,) = _named(events, "sched.block")
+    (judge,) = _named(events, "sched.judge")
+    assert execute.end_ns <= block.start_ns <= block.end_ns \
+        <= judge.start_ns
+    for leaf in ("sched.admit", "arbiter.split"):
+        for e in _named(events, leaf):
+            assert not _within(launch, e) and not _within(e, launch)
+
+
+def test_compile_counter_counts_a_fresh_trace_once(tmp_path):
+    def obs_counted_fn(x):
+        return x * 3.0 + 1.0
+
+    # JAX names a backend compile after the jitted computation
+    name = obs_counted_fn.__name__
+    compiled = f"jit({name})"
+    traces0 = COMPILES.counts("jit.trace").get(name, 0)
+    compiles0 = COMPILES.counts("jit.compile").get(compiled, 0)
+    f = jax.jit(obs_counted_fn)
+    x = jnp.ones(4)
+    f(x).block_until_ready()
+    assert COMPILES.counts("jit.trace").get(name, 0) == traces0 + 1
+    assert COMPILES.counts("jit.compile").get(compiled, 0) == compiles0 + 1
+    f(x).block_until_ready()                   # cached: no count
+    assert COMPILES.counts("jit.trace")[name] == traces0 + 1
+    assert COMPILES.counts("jit.compile")[compiled] == compiles0 + 1
+
+    # while the profiler records, a retrace is an instant on the timeline
+    events = _profiled(tmp_path,
+                       lambda: f(jnp.ones(5)).block_until_ready())
+    marks = [e for e in _named(events, "jit.trace")
+             if e.stats["fun"] == name]
+    assert len(marks) == 1 and marks[0].stats["seconds"] >= 0.0
+    assert COMPILES.counts("jit.trace")[name] == traces0 + 2
 
 
 # --------------------------------------------------------------------------
@@ -171,15 +288,6 @@ def test_arbiter_rebalance_routes_to_event_log():
     assert arb.rebalances == 1
     evs = EVENTS.recent(kind="arbiter.rebalance")
     assert evs and evs[-1]["cause"] == "drift"
-
-
-def test_event_log_mirrors_into_enabled_tracer():
-    TRACER.enable()
-    EVENTS.log("test.kind", value=7)
-    TRACER.disable()
-    (ev,) = TRACER.events()
-    assert ev["name"] == "test.kind" and ev["ph"] == "i"
-    assert ev["args"] == {"value": 7}
 
 
 # --------------------------------------------------------------------------
@@ -294,6 +402,18 @@ def test_system_metrics_counts_logged_events_by_kind():
     log_event("watchdog.timeout", timeout_s=0.2)
     text = system_metrics().render()
     assert 'repro_events_total{kind="watchdog.timeout"} 2' in text
+
+
+def test_system_metrics_exposes_jit_traces_and_compiles():
+    def obs_exposed_fn(x):
+        return x - 2.0
+
+    jax.jit(obs_exposed_fn)(jnp.ones(3)).block_until_ready()
+    text = system_metrics().render()
+    assert "# TYPE repro_jit_traces_total counter" in text
+    assert 'repro_jit_traces_total{fun="obs_exposed_fn"} 1' in text
+    assert 'repro_jit_compiles_total{fun="jit(obs_exposed_fn)"} 1' in text
+    assert "tracer_" not in text
 
 
 def test_counter_rejects_negative_increment():
