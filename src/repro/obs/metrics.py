@@ -252,7 +252,14 @@ def system_metrics(server=None,
         reg.counter("arbiter_rebalances_total",
                     "grant moves past hysteresis").inc(
             server.arbiter.rebalances)
-        for name, snap in server.telemetry().items():
+        snaps = server.telemetry()
+        reg.counter("serve_step_cache_hits_total",
+                    "launches served by an already compiled step").inc(
+            sum(snap["step_cache_hits"] for snap in snaps.values()))
+        reg.counter("serve_step_cache_misses_total",
+                    "launches that traced a new compiled step").inc(
+            sum(snap["step_cache_misses"] for snap in snaps.values()))
+        for name, snap in snaps.items():
             reg.counter("tenant_requests_total", "served requests",
                         tenant=name).inc(snap["requests"])
             reg.counter("tenant_batches_total", "executed batches",

@@ -28,6 +28,21 @@ tenant stack into one planned execution, so repeat batch shapes hit the
 plan cache with zero selector work.  With ``autotune=True`` the tunable
 sites of each executed plan run sweep-chosen tilings
 (``core.autotune.plan_tile_overrides``) instead of member defaults.
+
+Each batch runs through a compiled step (``_compiled_step``): one
+``jax.jit`` function per execution key — tenant, batch shape and dtype,
+ladder, the plan's per-site choice (site, IP, width, lowered or not),
+tile overrides and, in mesh mode, the target device — that stacks the
+frames, runs the frontend and returns the batch output with its
+per-frame rows.  A warm launch therefore traces nothing and moves
+nothing from the host; a grant move that keeps every site's choice
+re-uses the step.  The weights are arguments of the step, not
+constants.  Two cases stay eager: a tenant whose quantization error is
+measured (``measure_quant`` with a ladder: the report reads the error on
+the host) and a plan served through ``shard_map``
+(``_run_frontend_sharded``).  Fault injection, guarded screening and
+retry, and the f32 retry's re-plan (another ladder, so another key) act
+on the step's outputs, outside it.
 """
 from __future__ import annotations
 
@@ -48,7 +63,7 @@ from repro.runtime.faults import INJECTOR, InjectedFault
 from repro.runtime.guards import GuardPolicy, execute_guarded
 from repro.runtime.telemetry import TenantTelemetry
 
-_SIDE_CACHE_MAX = 256   # bound for the tile- and specs-caches
+_SIDE_CACHE_MAX = 256   # bound for the tile-, specs- and step-caches
 
 
 @dataclasses.dataclass
@@ -146,6 +161,8 @@ class AdaptiveServer:
         # bucket key -> site specs: spec construction runs jax.eval_shape
         # per block, so hot repeat buckets must not rebuild them
         self._specs_cache: Dict[tuple, tuple] = {}
+        # execution key -> jitted serving step (_compiled_step)
+        self._step_cache: Dict[tuple, Any] = {}
         self._next_rid = 0
 
     # -- admission ----------------------------------------------------------
@@ -355,20 +372,24 @@ class AdaptiveServer:
         return self._plan(tenant, (batch,) + tenant.input_shape,
                           jnp.dtype("float32"), tenant.ladder)[3]
 
-    def _attempt(self, tenant: Tenant, xb, *, retry_f32: bool = False,
+    def _attempt(self, tenant: Tenant, xs, *, retry_f32: bool = False,
                  launch: int = -1):
         """One execution attempt: route injected faults, (re)plan under
         the tenant's *current* slice — a degraded mesh re-plans here —
-        run the kernels, screen hooks applied by the caller.  Returns
-        ``(y, plan, quant_err)``.  ``retry_f32=True`` plans with the
+        run the kernels, screen hooks applied by the caller.  ``xs`` is
+        the batch's frames.  Returns ``(y, rows, plan, quant_err)``:
+        ``rows`` holds ``y``'s per-frame outputs when the compiled step
+        produced them, else None.  ``retry_f32=True`` plans with the
         precision ladder off (the guard's non-finite fallback)."""
         if INJECTOR.enabled:
             self._route_execute_faults(tenant)
         ladder = () if retry_f32 else tenant.ladder
+        batch_shape = (len(xs),) + tuple(xs[0].shape)
+        dtype = xs[0].dtype
         with (TRACER.span("serve.plan", launch=launch)
               if TRACER.enabled else NOOP_SPAN):
             specs, slice_budget, tenant_mesh, plan = self._plan(
-                tenant, xb.shape, xb.dtype, ladder)
+                tenant, batch_shape, dtype, ladder)
         if INJECTOR.enabled and tenant_mesh is not None:
             INJECTOR.check_devices(*self.arbiter.device_slice(tenant.name))
         tile_overrides = None
@@ -382,16 +403,31 @@ class AdaptiveServer:
                     self._tile_cache.pop(next(iter(self._tile_cache)))
                 self._tile_cache[tkey] = tile_overrides
         quant_report = {} if (ladder and tenant.measure_quant) else None
-        sharded = self._shardable(plan, xb)
+        sharded = self._shardable(plan, len(xs))
+        device = (self._granted_device(tenant)
+                  if tenant_mesh is not None and not sharded else None)
+        step = compiled = None
+        if quant_report is None and not sharded:
+            step, compiled = self._compiled_step(
+                tenant, plan, batch_shape, dtype, ladder, tile_overrides,
+                device)
+        rows = None
         with (TRACER.span("serve.dispatch", launch=launch,
-                          launches=plan.total_launches, sharded=sharded)
+                          launches=plan.total_launches, sharded=sharded,
+                          compiled=compiled or "eager")
               if TRACER.enabled else NOOP_SPAN):
-            if sharded:
+            if step is not None:
+                if device is not None:
+                    xs = jax.device_put(xs, device)
+                y, rows = step(tenant.params, xs)
+            elif sharded:
                 y = self._run_frontend_sharded(
-                    tenant, xb, plan, tile_overrides=tile_overrides)
-            else:
-                if tenant_mesh is not None:
-                    xb = jax.device_put(xb, self._granted_device(tenant))
+                    tenant, jnp.stack(xs), plan,
+                    tile_overrides=tile_overrides)
+            else:       # measure_quant: the report reads errors eagerly
+                xb = jnp.stack(xs)
+                if device is not None:
+                    xb = jax.device_put(xb, device)
                 y = apply_cnn_frontend(tenant.params, xb, network=plan,
                                        pool_window=tenant.pool_window,
                                        activation=tenant.activation,
@@ -400,12 +436,52 @@ class AdaptiveServer:
                                        tile_overrides=tile_overrides,
                                        fuse=self.fuse)
         if INJECTOR.enabled:
-            y = INJECTOR.perturb_output("output", y, tenant.name)
+            poisoned = INJECTOR.perturb_output("output", y, tenant.name)
+            if poisoned is not y:
+                y, rows = poisoned, None
         quant_err = 0.0
         if quant_report:
             from repro.quant.report import max_rel_error
             quant_err = max_rel_error(quant_report)
-        return y, plan, quant_err
+        return y, rows, plan, quant_err
+
+    def _compiled_step(self, tenant: Tenant, plan, batch_shape, dtype,
+                       ladder, tile_overrides, device):
+        """The jitted step that serves ``plan`` at one batch shape:
+        stack the frames, run the frontend on the plan's sites, return
+        the batch output and its per-frame rows.  Keyed on what
+        execution reads — the plan's per-site choices, not the budget
+        that produced them — so a grant move that keeps every choice
+        re-uses the compiled step.  The tenant fixes the weights' shapes,
+        the pool window and the activation.  The weights are arguments,
+        not constants of the executable.  Returns
+        ``(step, "hit"|"miss")``."""
+        key = (tenant.name, batch_shape, str(dtype), ladder,
+               tuple((s.spec.name, s.ip.name, s.precision_bits, s.lowered)
+                     for s in plan.sites),
+               None if tile_overrides is None else tuple(
+                   (site, tuple(sorted(kw.items())))
+                   for site, kw in sorted(tile_overrides.items())),
+               device)
+        step = self._step_cache.get(key)
+        if step is not None:
+            tenant.telemetry.step_cache_hits += 1
+            return step, "hit"
+        tenant.telemetry.step_cache_misses += 1
+        pool_window, activation = tenant.pool_window, tenant.activation
+
+        def run(params, xs):
+            y = apply_cnn_frontend(params, jnp.stack(xs), network=plan,
+                                   pool_window=pool_window,
+                                   activation=activation, ladder=ladder,
+                                   tile_overrides=tile_overrides)
+            return y, tuple(y[i] for i in range(len(xs)))
+
+        step = jax.jit(run)
+        if len(self._step_cache) >= _SIDE_CACHE_MAX:
+            self._step_cache.pop(next(iter(self._step_cache)))
+        self._step_cache[key] = step
+        return step, "miss"
 
     def _execute_batch(self, batch: List[Request], *,
                        deadline_budget_s: Optional[float] = None,
@@ -413,15 +489,15 @@ class AdaptiveServer:
         tenant = self.tenants[batch[0].tenant]
         with (TRACER.span("serve.stack", launch=launch, batch=len(batch))
               if TRACER.enabled else NOOP_SPAN):
-            xb = jnp.stack([r.x for r in batch])
+            xs = tuple(r.x for r in batch)
         hits0, misses0 = STATS.plan_hits, STATS.plan_misses
         policy = self._guards.get(tenant.name)
         out: Dict[str, Any] = {}
 
         def attempt(retry_f32: bool = False):
-            y, plan, qerr = self._attempt(tenant, xb, retry_f32=retry_f32,
-                                          launch=launch)
-            out["plan"], out["quant_err"] = plan, qerr
+            y, rows, plan, qerr = self._attempt(
+                tenant, xs, retry_f32=retry_f32, launch=launch)
+            out["rows"], out["plan"], out["quant_err"] = rows, plan, qerr
             return y
 
         if policy is None:
@@ -447,7 +523,7 @@ class AdaptiveServer:
                     for r in batch]
         with (TRACER.span("serve.results", launch=launch, batch=len(batch))
               if TRACER.enabled else NOOP_SPAN):
-            plan, quant_err = out["plan"], out["quant_err"]
+            rows, plan, quant_err = out["rows"], out["plan"], out["quant_err"]
             start = max(tenant.lane_free, max(r.arrival for r in batch))
             service = plan.calibrated_cycles(self.calibration)
             if INJECTOR.enabled:
@@ -460,13 +536,15 @@ class AdaptiveServer:
                 cache_hits=STATS.plan_hits - hits0,
                 cache_misses=STATS.plan_misses - misses0,
                 quant_err=quant_err)
-            return [Completion(rid=r.rid, tenant=r.tenant, result=y[i],
+            if rows is None:
+                rows = [y[i] for i in range(len(batch))]
+            return [Completion(rid=r.rid, tenant=r.tenant, result=row,
                                arrival=r.arrival, finished=finish,
                                batch_size=len(batch))
-                    for i, r in enumerate(batch)]
+                    for r, row in zip(batch, rows)]
 
     @staticmethod
-    def _shardable(plan, xb) -> bool:
+    def _shardable(plan, batch: int) -> bool:
         """True when the plan can run through the shard_map frontend
         path: a mesh plan whose sites are ALL batch-sharded at the mesh
         degree (a uniform layout needs no mid-chain relays inside the
@@ -484,7 +562,7 @@ class AdaptiveServer:
         if any(s.shard_axis != "batch" or s.shard_degree != d
                or s.lowered for s in plan.sites):
             return False
-        return xb.shape[0] % d == 0
+        return batch % d == 0
 
     def _granted_device(self, tenant: Tenant):
         """The first device of the tenant's granted slice, where a mesh

@@ -57,6 +57,10 @@ class TenantTelemetry:
     replans: int = 0            # grant moves that forced a re-plan
     plan_cache_hits: int = 0
     plan_cache_misses: int = 0
+    # compiled serving steps (AdaptiveServer._compiled_step): a hit
+    # re-uses a step traced for the same execution key, a miss traces
+    step_cache_hits: int = 0
+    step_cache_misses: int = 0
     max_quant_rel_err: float = 0.0
     # SLO accounting (dual clock: deadlines are wall-clock; the
     # percentile columns above stay est-cycles)
@@ -186,5 +190,7 @@ class TenantTelemetry:
             "plan_cache_misses": self.plan_cache_misses,
             "plan_cache_hit_rate": (self.plan_cache_hits / cache_lookups
                                     if cache_lookups else 0.0),
+            "step_cache_hits": self.step_cache_hits,
+            "step_cache_misses": self.step_cache_misses,
             "max_quant_rel_err": self.max_quant_rel_err,
         }

@@ -213,6 +213,8 @@ def test_served_launch_writes_every_span_nested(tmp_path):
     assert sum(e.stats["admitted"] for e in _named(events, "sched.admit")) \
         == 4
     (execute,) = _named(events, "serve.execute")
+    (dispatch,) = _named(events, "serve.dispatch")
+    assert dispatch.stats["compiled"] == "hit"    # launch 0 traced it
     (block,) = _named(events, "sched.block")
     (judge,) = _named(events, "sched.judge")
     assert execute.end_ns <= block.start_ns <= block.end_ns \
