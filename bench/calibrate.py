@@ -11,8 +11,9 @@ the cell's own sizes and load.  The benchmark's own runs run neither.
 
 ``readings`` prints one line a seed: the largest relative error of the
 served sample, first with the program serving, then with the control in
-the program's place (the plain reference in three bf16 passes,
-``reference.batch(..., passes=3)``, standing for the served frontend).
+the program's place: the network's plain reference in three bf16 passes,
+``batch(..., passes=3)`` of ``bench/networks/<network>.py``, put where
+the program's served entry is by that module's ``replace_served``.
 A configuration's ``rel_err_limit`` is set above the first readings and
 below the second (``PERF.md``).
 
@@ -37,25 +38,20 @@ for _p in (ROOT, ROOT / "src"):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-from bench import reference, traffic  # noqa: E402
+from bench import traffic  # noqa: E402
 from bench import run as bench_run  # noqa: E402
 
 SWEEP_METRICS = [{"name": "throughput_fps", "unit": "frames/s"},
                  {"name": "p95_ms", "unit": "ms"}]
 
 
-@contextlib.contextmanager
 def control_in_place(config: dict):
-    """The served frontend replaced by the reference in three bf16
+    """The served entry replaced by the network's reference in three bf16
     passes, for every batch the server launches."""
-    from repro.runtime import server
-    served = server.apply_cnn_frontend
-    server.apply_cnn_frontend = (
-        lambda p, images, **_: reference.batch(config, p, images, passes=3))
-    try:
-        yield
-    finally:
-        server.apply_cnn_frontend = served
+    network = bench_run.network_for(config)
+    return network.replace_served(
+        lambda served: lambda params, images, **_: network.batch(
+            config, params, images, passes=3))
 
 
 def seeds(text: str):

@@ -4,21 +4,23 @@
     python bench/run.py --workload vgg16.sat --seed 7 --seconds 10 --trace 0
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
-its configuration in the file that entry names, its traffic mix in
+its configuration in the file that entry names, the configuration's
+network in ``bench/networks/<network>.py``, its traffic mix in
 ``bench/traffic/<traffic>.json`` and each metric's reader in
 ``bench/metrics/<metric>.py`` (see ``bench/README.md``).
 
 A run makes the weights and a frame pool from ``--seed`` on the device,
-registers the tenant with ``SLOScheduler``, warms the batch sizes the mix
+registers the tenant with ``SLOScheduler`` (on a mesh of the cell's chips
+where it asks for more than one), warms the batch sizes the mix
 produces, then drives the mix for ``--seconds`` through
 ``SLOScheduler.submit`` and ``SLOScheduler.run`` (one launch per pump).
 ``--trace 1`` runs the same window under the profiler and reports the
 per-layer metrics in place of the end-to-end ones.
 
 After the window the outputs of a seeded sample of the served frames are
-compared with the plain f32 reference (``bench/reference.py``); the run
-is ``correct`` when the largest relative error is within the
-configuration's ``rel_err_limit``.
+compared with the network's plain f32 reference; the run is ``correct``
+when the largest relative error is within the configuration's
+``rel_err_limit``.
 
 Without a TPU, or with fewer chips than the cell asks for, the run exits
 with code 2 and prints no result.
@@ -53,6 +55,8 @@ from bench import reference, trace, traffic  # noqa: E402
 
 BENCH_DIR = ROOT / "bench"
 TRACE_ROOT = BENCH_DIR / "_out" / "trace"
+METRICS_DIR = BENCH_DIR / "metrics"
+NETWORKS_DIR = BENCH_DIR / "networks"
 # Served outputs compared with the reference, drawn from the seed.
 SAMPLE = 64
 PEAKS_FILE = BENCH_DIR / "peaks.json"
@@ -89,14 +93,27 @@ def metrics_for(bench: dict, workload: str, traced: bool) -> List[dict]:
             if "workloads" not in m or workload in m["workloads"]]
 
 
-def load_reader(name: str):
-    """``read(ctx)`` from ``bench/metrics/<name>.py``."""
-    path = BENCH_DIR / "metrics" / f"{name}.py"
+def load_module(directory: Path, name: str):
+    """The module ``<directory>/<name>.py``."""
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"bench_{directory.name}_"
+        + name.replace(".", "_").replace("-", "_"),
+        directory / f"{name}.py")
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_reader(name: str):
+    """``read(ctx)`` from ``bench/metrics/<name>.py``."""
+    return load_module(METRICS_DIR, name).read
+
+
+def network_for(config: dict):
+    """The module ``bench/networks/<network>.py`` that the configuration's
+    ``network`` names: its weights, reference, work counts and how the
+    program is given it (``bench/README.md``)."""
+    return load_module(NETWORKS_DIR, config["network"])
 
 
 def peaks_for(device_kind: str) -> dict:
@@ -146,6 +163,7 @@ class Context:
     events: Optional[list] = None        # trace.Event list (traced run)
     window_ns: Optional[tuple] = None    # bench.window span on trace clock
     planes: Optional[list] = None        # device planes of the cell
+    network: Any = None                  # the configuration's network module
 
     _busy: dict = dataclasses.field(default_factory=dict)
 
@@ -157,17 +175,18 @@ class Context:
         return self._busy[plane]
 
 
-def serve(config: dict, mix: dict, *, seed: int, seconds: float,
+def serve(config: dict, network, mix: dict, *, seed: int, seconds: float,
           traced: bool, trace_dir: Path, devices, started: float,
           log=print) -> dict:
     """Set up, warm, drive the window, check the outputs.  Everything
-    but the look for a chip."""
+    but the look for a chip.  More than one device is served as one
+    mesh."""
     import jax
     from repro.core.plan import STATS
+    from repro.core.resources import MeshSpec
     from repro.runtime import AdaptiveServer, SLOScheduler, SLOSpec
 
-    params, pool = reference.make_weights_and_frames(config, seed,
-                                                     int(mix["pool"]))
+    params, pool = network.make(config, seed, int(mix["pool"]))
     frames = [pool[i] for i in range(pool.shape[0])]
     jax.block_until_ready(frames)
 
@@ -175,13 +194,12 @@ def serve(config: dict, mix: dict, *, seed: int, seconds: float,
         log(f"[{time.perf_counter() - started:.3f} s] {what}")
 
     stamp("weights and frames made")
-    server = AdaptiveServer(max_batch=int(mix["max_batch"]))
+    mesh = MeshSpec(devices=len(devices)) if len(devices) > 1 else None
+    server = AdaptiveServer(max_batch=int(mix["max_batch"]), mesh=mesh)
     sched = SLOScheduler(server)
     tenant = config["name"]
-    sched.register(tenant, params, tuple(config["image"]),
-                   slo=SLOSpec(deadline_s=float(mix["deadline_s"])),
-                   pool_window=tuple(config["pool_window"]),
-                   activation=config["activation"])
+    network.register(sched, tenant, config, params,
+                     SLOSpec(deadline_s=float(mix["deadline_s"])))
     stamp("tenant registered")
 
     def pump():
@@ -254,8 +272,7 @@ def serve(config: dict, mix: dict, *, seed: int, seconds: float,
     if sample:
         import numpy as np
         used = sorted({idx for idx, _ in sample})
-        ref = reference.forward(config, params,
-                                pool[np.asarray(used)])
+        ref = network.forward(config, params, pool[np.asarray(used)])
         row = {idx: i for i, idx in enumerate(used)}
         errs = [float(e) for e in reference.rel_errors(
             np.stack([y for _, y in sample]),
@@ -297,12 +314,13 @@ def measure(workload: str, config: dict, mix: dict, chips: int,
     """One run of a cell on ``devices``; returns the result line."""
     import jax
     trace_dir = TRACE_ROOT / workload
-    out = serve(config, mix, seed=seed, seconds=seconds, traced=traced,
-                trace_dir=trace_dir, devices=devices, started=started,
-                log=log)
+    network = network_for(config)
+    out = serve(config, network, mix, seed=seed, seconds=seconds,
+                traced=traced, trace_dir=trace_dir, devices=devices,
+                started=started, log=log)
     record = out["record"]
     ctx = Context(workload, config, mix, chips, peaks, out["setup_s"],
-                  record)
+                  record, network=network)
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": out["peak"]}

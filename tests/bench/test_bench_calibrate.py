@@ -51,9 +51,10 @@ def test_sweep_runs_each_count_and_prints_the_knee(monkeypatch, capsys):
 
     from bench import run as bench_run
     from bench import traffic
-    tiny = {"name": "tiny", "image": [16, 16, 3], "channels": [3, 4, 8],
-            "kernel": 3, "pool_window": [2, 2], "activation": "relu",
-            "d_model": 8, "dtype": "float32", "rel_err_limit": 2e-06}
+    tiny = {"name": "tiny", "network": "cnn_chain", "image": [16, 16, 3],
+            "channels": [3, 4, 8], "kernel": 3, "pool_window": [2, 2],
+            "activation": "relu", "d_model": 8, "dtype": "float32",
+            "rel_err_limit": 2e-06}
     mix = {"loop": "open", "cameras": 1, "fps": 30.0, "phase_seed": 0,
            "pool": 8, "max_batch": 4, "deadline_s": 5.0,
            "warm_batches": [1, 2, 3, 4], "warm_s": 0.1}

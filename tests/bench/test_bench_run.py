@@ -23,11 +23,12 @@ import pytest
 from bench import calibrate, reference
 from bench import run as bench_run
 from bench import traffic
+from bench.networks import cnn_chain
 
 ROOT = Path(__file__).resolve().parents[2]
-TINY = {"name": "tiny", "image": [16, 16, 3], "channels": [3, 4, 8],
-        "kernel": 3, "pool_window": [2, 2], "activation": "relu",
-        "d_model": 8, "dtype": "float32"}
+TINY = {"name": "tiny", "network": "cnn_chain", "image": [16, 16, 3],
+        "channels": [3, 4, 8], "kernel": 3, "pool_window": [2, 2],
+        "activation": "relu", "d_model": 8, "dtype": "float32"}
 CLOSED = {"loop": "closed", "in_flight": 8, "pool": 8, "max_batch": 4,
           "deadline_s": 600.0, "warm_batches": [4],
           "warm_s": 0.1}
@@ -113,12 +114,10 @@ def _leave_out_half(y):
 
 
 @pytest.mark.parametrize("fault", [_alter_one_answer, _leave_out_half])
-def test_a_broken_served_path_is_not_correct(monkeypatch, fault):
-    from repro.runtime import server
-    served = server.apply_cnn_frontend
-    monkeypatch.setattr(server, "apply_cnn_frontend",
-                        lambda *a, **k: fault(served(*a, **k)))
-    line = _measure("vgg16.sat", CLOSED)
+def test_a_broken_served_path_is_not_correct(fault):
+    with cnn_chain.replace_served(
+            lambda served: lambda *a, **k: fault(served(*a, **k))):
+        line = _measure("vgg16.sat", CLOSED)
     assert line["correct"] is False
     assert line["checks"]["max_rel_err"]["value"] > _limit("vgg16_d")
 
@@ -141,9 +140,9 @@ def test_the_control_reads_above_the_limit(name, config):
     config = _control_config(name, config)
     limit = _limit(name)
     for seed in (1, 2, 3):
-        params, frames = reference.make_weights_and_frames(config, seed, 4)
-        exact = reference.forward(config, params, frames)
-        control = reference.forward(config, params, frames, passes=3)
+        params, frames = cnn_chain.make(config, seed, 4)
+        exact = cnn_chain.forward(config, params, frames)
+        control = cnn_chain.forward(config, params, frames, passes=3)
         assert reference.rel_errors(control, exact).max() > limit
 
 
