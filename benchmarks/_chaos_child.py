@@ -162,9 +162,8 @@ def main() -> None:
             # this point NOTHING may plan cold
             t = srv.tenants["a"]
             for b in range(1, MAX_BATCH + 1):
-                specs = srv._specs(t.params, (b,) + t.input_shape,
-                                   "float32", t.pool_window, t.activation,
-                                   t.ladder)
+                specs = srv._specs(t.net, t.params, (b,) + t.input_shape,
+                                   "float32", t.ladder)
                 replan(specs, srv.arbiter.budget_for("a"), fuse=srv.fuse,
                        mesh=srv.arbiter.mesh_for("a"))
             spares = srv.prewarm_spares(losses=1)

@@ -132,12 +132,11 @@ def compiled_step(server, params, x):
     seconds, HLO text)."""
     import functools
     import jax
-    from repro.models.frontends import apply_cnn_frontend
+    from repro.runtime.server import serve_frontend
     tenant = server.tenants[TENANT]
     plan = server.plan_for(TENANT, x.shape[0])
-    step = jax.jit(functools.partial(
-        apply_cnn_frontend, network=plan, pool_window=tenant.pool_window,
-        activation=tenant.activation))
+    step = jax.jit(functools.partial(serve_frontend, tenant.net,
+                                     network=plan))
     t0 = time.perf_counter()
     compiled = step.lower(params, x).compile()
     return time.perf_counter() - t0, compiled.as_text()

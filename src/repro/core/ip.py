@@ -190,12 +190,13 @@ class IPFamily:
 
     **Fusion contract** (docs/adaptive_ips.md, "Fusion contract"): a
     family whose members absorb a *chain* of op families into one launch
-    declares the chain in ``fuses`` (program order, e.g. ``("conv2d",
-    "pool2d", "activation")``) and registers a ``fuse_sites`` adapter
-    mapping that many adjacent SiteSpecs to the single fused SiteSpec —
-    or ``None`` when the run is not fusable (wrong knobs, shapes that
-    don't chain).  ``plan_network(..., fuse=True)`` scans every planned
-    graph for such runs generically; it never hard-codes a family.
+    declares its chains in ``fuse_chains`` (each in program order, e.g.
+    ``(("conv2d", "pool2d", "activation"),)``; several chains longest
+    first) and registers a ``fuse_sites`` adapter mapping that many
+    adjacent SiteSpecs to the single fused SiteSpec — or ``None`` when
+    the run is not fusable (wrong knobs, shapes that don't chain).
+    ``plan_network(..., fuse=True)`` scans every planned graph for such
+    runs generically; it never hard-codes a family.
     """
 
     name: str
@@ -203,9 +204,9 @@ class IPFamily:
     reference: Optional[Callable[..., Any]] = None
     site_adapter: Optional[Callable[[SiteSpec], SiteRequest]] = None
     quantizable: bool = True
-    fuses: Tuple[str, ...] = ()
     fuse_sites: Optional[Callable[[Tuple[SiteSpec, ...]],
                                   Optional[SiteSpec]]] = None
+    fuse_chains: Tuple[Tuple[str, ...], ...] = ()
 
     def plan_site(self, spec: SiteSpec) -> SiteRequest:
         if spec.family != self.name:

@@ -118,7 +118,7 @@ def _fused_ref(x, w, *, window=(2, 2), stride=None, mode="max",
 
 
 CNN_FUSED = IPFamily("cnn_fused", reference=_fused_ref,
-                     fuses=("conv2d", "pool2d", "activation"))
+                     fuse_chains=(("conv2d", "pool2d", "activation"),))
 CNN_FUSED.register(KernelIP(
     name="cnn_fused.fused_vpu", family="cnn_fused",
     impl=fused_mod.fused_cnn_vpu, footprint_fn=fused_mod.footprint_vpu,
@@ -133,6 +133,46 @@ CNN_FUSED.register(KernelIP(
     description="Whole CNN block in one launch: one MXU dot per tap and "
                 "conv row, pool + activation in register; single HBM "
                 "write."))
+
+# --------------------------------------------------------------------------
+# res_conv family — conv -> bias -> optional residual add -> optional ReLU
+# as ONE launch: the unit a residual network repeats.  The planner
+# substitutes it for a (conv, add, act), (conv, act) or lone (conv) run of
+# a residual graph's sites (those whose conv carries a bias); the add
+# family is the shortcut add such a run keeps when it stays unfused.
+# --------------------------------------------------------------------------
+from repro.kernels.eltwise import add as add_mod  # noqa: E402
+from repro.kernels.fused import res_conv as res_conv_mod  # noqa: E402
+
+ADD = IPFamily("add", reference=add_mod.add_ref, quantizable=False)
+ADD.register(KernelIP(
+    name="add.add_vpu", family="add", impl=add_mod.add_vpu,
+    footprint_fn=add_mod.footprint, uses_mxu=False,
+    supports_dtypes=("float32",), compiled_dtypes=("float32",),
+    tags=("residual",),
+    description="Elementwise residual add on the VPU, one pass over HBM."))
+
+
+def _res_conv_ref(x, w, b, residual=None, *, stride=1, padding=0,
+                  relu=True):
+    """Composite oracle: conv, bias, add and ReLU in plain jnp."""
+    y = conv2d_ref(x, w, stride=stride, padding=padding) + b
+    if residual is not None:
+        y = y + residual
+    return jnp.maximum(y, 0.0) if relu else y
+
+
+RES_CONV = IPFamily("res_conv", reference=_res_conv_ref, quantizable=False,
+                    fuse_chains=(("conv2d", "add", "activation"),
+                                 ("conv2d", "activation"), ("conv2d",)))
+RES_CONV.register(KernelIP(
+    name="res_conv.res_mxu", family="res_conv", impl=res_conv_mod.res_conv,
+    footprint_fn=res_conv_mod.footprint, uses_mxu=True,
+    supports_dtypes=("float32",), compiled_dtypes=("float32",),
+    tags=("fused", "residual", "analogue:Conv2"),
+    description="Residual unit in one launch: one MXU dot per tap and "
+                "conv row (strided, zero-padded), then bias, shortcut add "
+                "and ReLU on the VMEM-resident row; single HBM write."))
 
 # --------------------------------------------------------------------------
 # matmul family — the LM-hot-path generalization.
@@ -205,7 +245,8 @@ SSM_SCAN.register(KernelIP(
                 "O(T·(Di+Ds)) vs the scan twin's O(T·Di·Ds)."))
 
 FAMILIES = {f.name: f for f in (CONV2D, POOL2D, ACTIVATION, CNN_FUSED,
-                                MATMUL, ATTENTION, SSM_SCAN)}
+                                ADD, RES_CONV, MATMUL, ATTENTION,
+                                SSM_SCAN)}
 
 # --------------------------------------------------------------------------
 # Site adapters — what makes each family *plannable*.  An adapter maps a
@@ -231,19 +272,33 @@ def _conv2d_adapter(spec: SiteSpec) -> SiteRequest:
     want = (("conv2d.ip3_packed", "conv2d.ip4_dual")
             if spec.knob("dual", False)
             else ("conv2d.ip1_vpu", "conv2d.ip2_mxu"))
+    if spec.knob("style") == "mxu":
+        # a site pinned to the MXU-style member, where the uncalibrated
+        # cost model would rank the VPU member first (ROADMAP S5)
+        want = ("conv2d.ip2_mxu",)
+    # stride, padding and bias reach the single-stream members'
+    # footprints only where a site sets them, so a VALID stride-1 site
+    # without a bias prices as it always has
+    geometry = tuple((k, spec.knob(k)) for k in ("stride", "padding", "bias")
+                     if spec.knob(k) is not None)
     return SiteRequest(
         candidates=tuple(CONV2D[name] for name in want),
         fp_args=(n, h, w_, cin, kh, kw, cout),
-        fp_kwargs=(("itemsize", jnp.dtype(spec.dtype).itemsize),),
+        fp_kwargs=(("itemsize", jnp.dtype(spec.dtype).itemsize),)
+        + geometry,
         op_bits=_bits(spec.dtype))
 
 
 def _pool2d_adapter(spec: SiteSpec) -> SiteRequest:
     from repro.kernels.pool2d.ref import check_pool_geometry
     (x_shape,) = spec.shapes
-    (kh, kw), (sh, sw) = check_pool_geometry(
-        x_shape, spec.knob("window", (2, 2)), spec.knob("stride"))
+    # a padded site (``padding=``: zeros on every side, which the max of
+    # a non-negative input ignores) is priced on its padded input
     n, h, w_, c = x_shape
+    pad = spec.knob("padding", 0)
+    h, w_ = h + 2 * pad, w_ + 2 * pad
+    (kh, kw), (sh, sw) = check_pool_geometry(
+        (n, h, w_, c), spec.knob("window", (2, 2)), spec.knob("stride"))
     return SiteRequest(
         candidates=(POOL2D["pool2d.pool_vpu"], POOL2D["pool2d.pool_im2col"]),
         fp_args=(n, h, w_, c, kh, kw, sh, sw),
@@ -327,7 +382,8 @@ def _cnn_fuse_sites(run) -> "SiteSpec | None":
     dual-stream conv, shapes that do not chain conv->pool->act, or a
     pool window the conv output cannot host."""
     conv, pool, act = run
-    if conv.knob("dual", False):
+    if (conv.knob("dual", False) or conv.knob("stride", 1) != 1
+            or conv.knob("padding", 0)):
         return None
     x_shape, w_shape = conv.shapes
     n, h, w_, cin = x_shape
@@ -354,11 +410,69 @@ def _cnn_fuse_sites(run) -> "SiteSpec | None":
         mode=pool.knob("mode", "max"), kind=act.knob("kind", "relu"))
 
 
+def _add_adapter(spec: SiteSpec) -> SiteRequest:
+    x_shape, _ = spec.shapes
+    return SiteRequest(
+        candidates=(ADD["add.add_vpu"],), fp_args=tuple(x_shape),
+        fp_kwargs=(("itemsize", jnp.dtype(spec.dtype).itemsize),),
+        op_bits=_bits(spec.dtype))
+
+
+def _res_conv_adapter(spec: SiteSpec) -> SiteRequest:
+    x_shape, w_shape = spec.shapes
+    n, h, w_, cin = x_shape
+    kh, kw, _, cout = w_shape
+    return SiteRequest(
+        candidates=(RES_CONV["res_conv.res_mxu"],),
+        fp_args=(n, h, w_, cin, kh, kw, cout),
+        fp_kwargs=(("itemsize", jnp.dtype(spec.dtype).itemsize),
+                   ("stride", spec.knob("stride", 1)),
+                   ("padding", spec.knob("padding", 0)),
+                   ("residual", spec.knob("residual", False)),
+                   ("relu", spec.knob("relu", True))),
+        op_bits=_bits(spec.dtype))
+
+
+def _conv_out_shape(conv: SiteSpec):
+    """(N, Ho, Wo, Cout) a conv2d site writes."""
+    from repro.kernels.conv2d.inner import conv_out_hw
+    (n, h, w_, _), (kh, kw, _, cout) = conv.shapes
+    return (n, *conv_out_hw(h, w_, kh, kw, conv.knob("stride", 1),
+                            conv.knob("padding", 0)), cout)
+
+
+def _res_fuse_sites(run) -> "SiteSpec | None":
+    """Map a residual graph's (conv, add, act), (conv, act) or lone
+    (conv) run — a projection shortcut — to the single ``res_conv``
+    site, or None when it is not fusable: a conv without a bias (not a
+    residual graph's), a dual-stream conv, an activation other than
+    ReLU, or shapes that do not chain."""
+    conv, *rest = run
+    if conv.knob("dual", False) or not conv.knob("bias", False):
+        return None
+    out = _conv_out_shape(conv)
+    if rest and (rest[-1].knob("kind", "relu") != "relu"
+                 or tuple(rest[-1].shapes[0]) != out):
+        return None
+    residual = len(rest) == 2
+    if residual and any(tuple(s) != out for s in rest[0].shapes):
+        return None
+    base = conv.name[:-len(".conv")] if conv.name.endswith(".conv") \
+        else conv.name
+    return SiteSpec.make(
+        f"{base}.fused", "res_conv", conv.shapes, conv.dtype,
+        stride=conv.knob("stride", 1), padding=conv.knob("padding", 0),
+        residual=residual, relu=bool(rest))
+
+
 CONV2D.site_adapter = _conv2d_adapter
 POOL2D.site_adapter = _pool2d_adapter
 ACTIVATION.site_adapter = _activation_adapter
 CNN_FUSED.site_adapter = _cnn_fused_adapter
 CNN_FUSED.fuse_sites = _cnn_fuse_sites
+ADD.site_adapter = _add_adapter
+RES_CONV.site_adapter = _res_conv_adapter
+RES_CONV.fuse_sites = _res_fuse_sites
 MATMUL.site_adapter = _matmul_adapter
 ATTENTION.site_adapter = _attention_adapter
 

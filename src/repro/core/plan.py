@@ -30,7 +30,7 @@ the single selection engine behind every family:
   (``plan_cache_stats()``: hits, misses, evictions, occupancy) — the
   serving runtime surfaces these per tenant.
 * **Fusion groups** (``fuse=True``): adjacent site runs a registered
-  fused family absorbs (``IPFamily.fuses`` + ``fuse_sites``, e.g.
+  fused family absorbs (``IPFamily.fuse_chains`` + ``fuse_sites``, e.g.
   conv->pool->act -> one ``cnn_fused`` site) are substituted when the
   fused member's combined footprint is feasible at the full budget and
   prices at or below the unfused chain, with per-group fallback to the
@@ -670,7 +670,7 @@ def plan_network(specs: Iterable[SiteSpec],
     on every budget; pass ``fuse=False`` to opt out) turns on
     **fusion-aware planning**: adjacent runs a
     registered fused family absorbs (e.g. conv->pool->act, declared via
-    ``IPFamily.fuses``) are substituted by the single fused site when
+    ``IPFamily.fuse_chains``) are substituted by the single fused site when
     the fused member is feasible at the full budget and its combined
     footprint prices at or below the unfused chain's; groups whose
     fused footprint then breaks the partition are unfused again one at
@@ -906,16 +906,17 @@ def _fusion_groups(specs: Tuple[SiteSpec, ...]):
     """Adjacent runs some fused family absorbs: [(start, length,
     fused_spec)], non-overlapping, left-to-right greedy."""
     from repro.core.library import FAMILIES
-    fusers = [f for f in FAMILIES.values() if f.fuses and f.fuse_sites]
+    chains = [(f, chain) for f in FAMILIES.values() if f.fuse_sites
+              for chain in f.fuse_chains]
     groups = []
     i = 0
     while i < len(specs):
         step = 1
-        for fam in fusers:
-            ln = len(fam.fuses)
+        for fam, chain in chains:
+            ln = len(chain)
             run = specs[i:i + ln]
             if (len(run) == ln
-                    and tuple(s.family for s in run) == fam.fuses):
+                    and tuple(s.family for s in run) == chain):
                 fspec = fam.fuse_sites(tuple(run))
                 if fspec is not None:
                     groups.append((i, ln, fspec))

@@ -46,8 +46,12 @@ def _maybe_reduce(y: jnp.ndarray, reduce_axis: Optional[str],
 def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, ip: Optional[str] = None,
            budget: Optional[ResourceBudget] = None, ladder=(),
            reduce_axis: Optional[str] = None,
-           reduce: str = "psum", **tile_kwargs) -> jnp.ndarray:
+           reduce: str = "psum", stride: int = 1, padding: int = 0,
+           **tile_kwargs) -> jnp.ndarray:
     """Single-stream convolution through a selected IP (Conv1/Conv2).
+
+    ``stride`` and zero ``padding`` (on every side) reach the member
+    as they are; the quantized path runs only the VALID stride-1 conv.
 
     ``tile_kwargs`` forward tiling parameters to the member (e.g.
     ``block_cout=`` for ``ip2_mxu``, typically from
@@ -63,10 +67,16 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, ip: Optional[str] = None,
     if ip is None:
         from repro.core.ip import SiteSpec
         from repro.core.plan import plan_single
+        knobs = ({"stride": stride, "padding": padding}
+                 if (stride, padding) != (1, 0) else {})
         spec = SiteSpec.make("conv2d", "conv2d", (x.shape, w.shape),
-                             x.dtype, ladder=ladder, dual=False)
+                             x.dtype, ladder=ladder, dual=False, **knobs)
         planned = plan_single(spec, budget)
         if planned.lowered:
+            if knobs:
+                raise ValueError("the quantized conv runs only VALID "
+                                 "stride-1 convs; plan a strided or "
+                                 "padded conv without a ladder")
             from repro.quant.ops import quantized_conv2d
             y = quantized_conv2d(x, w, bits=planned.precision_bits,
                                  ip=planned.ip.name)
@@ -76,6 +86,8 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, ip: Optional[str] = None,
     if ip not in _SINGLE:
         raise KeyError(f"{ip!r} is not a single-stream conv IP "
                        f"(have {sorted(_SINGLE)})")
+    if (stride, padding) != (1, 0):
+        tile_kwargs = dict(tile_kwargs, stride=stride, padding=padding)
     y = _SINGLE[ip](x, w, **tile_kwargs)
     return _maybe_reduce(y, reduce_axis, reduce)
 

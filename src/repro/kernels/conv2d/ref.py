@@ -5,6 +5,9 @@ Contract shared by all four IPs:
   w : (KH, KW, Cin, Cout)       kernel coefficients
   y : (N, H-KH+1, W-KW+1, Cout) VALID padding, stride 1
 
+The single-stream members also take ``stride`` and zero ``padding`` on
+every side: y is then (N, (H+2P-KH)//S+1, (W+2P-KW)//S+1, Cout).
+
 Integer inputs accumulate exactly in int32 (the paper's fixed-point
 contract); float inputs accumulate in float32 at full precision (on a
 TPU the default would be one bf16 MXU pass, ~1e-3 relative error).
@@ -21,12 +24,14 @@ def _acc_dtype(x_dtype, w_dtype):
     return jnp.float32
 
 
-def conv2d_ref(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+def conv2d_ref(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1,
+               padding: int = 0) -> jnp.ndarray:
     """Reference convolution (cross-correlation, as in CNN frameworks)."""
     acc = _acc_dtype(x.dtype, w.dtype)
     out = lax.conv_general_dilated(
         x.astype(acc), w.astype(acc),
-        window_strides=(1, 1), padding="VALID",
+        window_strides=(stride, stride),
+        padding=((padding, padding), (padding, padding)),
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         precision=None if acc == jnp.int32 else lax.Precision.HIGHEST,
         preferred_element_type=acc)

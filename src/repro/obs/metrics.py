@@ -14,6 +14,11 @@ each behind its own ad-hoc dict.  This module unifies them:
 * ``system_metrics(server=None)`` — the collector: walks the planner
   stats, plan cache, event log, compile counter, and (when given a
   server) the arbiter + per-tenant telemetry into a fresh registry.
+* ``planned_site_counts(plan)`` — a plan's sites by family, member
+  and fused or not: the ``planned_sites`` gauge, one per tenant and
+  batch size of the plan it last ran (``TenantTelemetry.plans``).
+  Their sum is the plan's site count and, one launch a site, its
+  ``total_launches``.
 * ``percentile(values, q)`` — THE percentile estimator.
   ``TenantTelemetry.latency_percentile`` and ``Histogram.quantile``
   both delegate here, so serving telemetry and metrics exposition can
@@ -31,6 +36,18 @@ from typing import Deque, Dict, Iterable, List, Optional, Sequence, Tuple
 
 HISTOGRAM_WINDOW = 4096
 _QUANTILES = (0.5, 0.9, 0.95, 0.99)
+
+
+def planned_site_counts(plan) -> Dict[Tuple[str, str, bool], int]:
+    """``plan``'s sites counted by (family, member, fused): a fused site
+    is one of a family that absorbs a chain of op sites."""
+    from repro.core.library import FAMILIES
+    counts: Dict[Tuple[str, str, bool], int] = {}
+    for s in plan.sites:
+        key = (s.spec.family, s.ip.name,
+               bool(FAMILIES[s.spec.family].fuse_chains))
+        counts[key] = counts.get(key, 0) + 1
+    return counts
 
 
 def percentile(values: Sequence[float], q: float) -> float:
@@ -304,6 +321,14 @@ def system_metrics(server=None,
                                   "measured wall latency of SLO-tracked "
                                   "requests", tenant=name)
             whist.observe_many(tenant.telemetry.wall_latencies)
+            for batch, plan in sorted(tenant.telemetry.plans.items()):
+                for (family, member, fused), n in sorted(
+                        planned_site_counts(plan).items()):
+                    reg.gauge("planned_sites",
+                              "sites of the plan a batch size last ran",
+                              tenant=name, batch=str(batch), family=family,
+                              member=member,
+                              fused=str(fused).lower()).set(n)
     if scheduler is not None:
         for name, depth in scheduler.stats()["queue_depths"].items():
             reg.gauge("scheduler_queue_depth",

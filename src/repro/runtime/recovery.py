@@ -46,6 +46,7 @@ from repro.core.calibrate_cost import calibration_key
 from repro.core.plan import (STATS, clear_plan_cache, export_plan_cache,
                              import_plan_cache)
 from repro.core.resources import MeshSpec, ResourceBudget
+from repro.models.frontends import network_from_dict
 from repro.checkpoint.store import restore_blind, save
 from repro.obs.trace import log_event
 from repro.runtime.fault_tolerance import Watchdog
@@ -82,8 +83,7 @@ def server_state(server: AdaptiveServer,
         "tenants": {
             name: {
                 "input_shape": list(t.input_shape),
-                "pool_window": list(t.pool_window),
-                "activation": t.activation,
+                "net": t.net.to_dict(),
                 "ladder": list(t.ladder),
                 "measure_quant": t.measure_quant,
                 "floor": t.floor,
@@ -149,8 +149,7 @@ def recover_server(ckpt_dir: str, *, step: Optional[int] = None,
         t = extra["tenants"][name]
         tenant = server.register(
             name, params[name], tuple(t["input_shape"]),
-            pool_window=tuple(t["pool_window"]),
-            activation=t["activation"], ladder=tuple(t["ladder"]),
+            net=network_from_dict(t["net"]), ladder=tuple(t["ladder"]),
             measure_quant=t["measure_quant"])
         if abs(tenant.floor - t["floor"]) > 1e-9:
             raise ValueError(

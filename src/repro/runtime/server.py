@@ -55,7 +55,8 @@ import jax.numpy as jnp
 from repro.core.plan import (STATS, network_min_fraction, plan_network,
                              replan)
 from repro.core.resources import MeshSpec, ResourceBudget, check_device
-from repro.models.frontends import apply_cnn_frontend, cnn_frontend_site_specs
+from repro.models.frontends import (ChainNet, apply_cnn_frontend,
+                                    apply_resnet_frontend)
 from repro.obs.trace import NOOP_SPAN, TRACER, log_event
 from repro.runtime.arbiter import BudgetArbiter, TenantShare
 from repro.runtime.batching import Request, ShapeBucketQueue
@@ -66,6 +67,17 @@ from repro.runtime.telemetry import TenantTelemetry
 _SIDE_CACHE_MAX = 256   # bound for the tile-, specs- and step-caches
 
 
+def serve_frontend(net, params, images, **kwargs):
+    """``net``'s frontend on ``images``: this module's
+    ``apply_resnet_frontend`` for a residual network, its
+    ``apply_cnn_frontend`` for a chain, with the description's own
+    arguments.  The entry is read from the module when called, so a
+    caller may put a wrapper in its place for every batch served."""
+    entry = (apply_resnet_frontend if net.kind == "residual"
+             else apply_cnn_frontend)
+    return entry(params, images, **net.apply_kwargs(), **kwargs)
+
+
 @dataclasses.dataclass
 class Tenant:
     """One registered CNN frontend and its serving state."""
@@ -73,8 +85,7 @@ class Tenant:
     name: str
     params: Any
     input_shape: Tuple[int, ...]        # per-sample (H, W, C)
-    pool_window: Tuple[int, int]
-    activation: str
+    net: Any                            # ChainNet | ResidualNet
     ladder: Tuple[int, ...]
     measure_quant: bool
     floor: float                        # min feasible device fraction
@@ -92,7 +103,10 @@ class Completion:
 
     rid: int
     tenant: str
-    result: Any                         # (S, d_model) patch embeddings
+    result: Any                         # what the tenant's network
+                                        # returns for one frame: a chain's
+                                        # (S, d_model) patch embeddings, a
+                                        # residual net's (classes,) logits
     arrival: float
     finished: float
     batch_size: int
@@ -166,11 +180,18 @@ class AdaptiveServer:
         self._next_rid = 0
 
     # -- admission ----------------------------------------------------------
-    def register(self, name: str, params, input_shape, *,
+    def register(self, name: str, params, input_shape, *, net=None,
                  pool_window=(2, 2), activation: str = "relu",
                  ladder: Tuple[int, ...] = (),
                  measure_quant: bool = False) -> Tenant:
         """Register a CNN frontend as a tenant.
+
+        ``net`` describes the network (``models/frontends.py``:
+        ``ChainNet`` or ``ResidualNet``): its site specs at a batch
+        shape and the arguments its frontend runs a plan with.  Without
+        it the tenant is the chain ``ChainNet(pool_window, activation)``.
+        A residual network is served on one device: a mesh server
+        refuses it.
 
         Prices the tenant up front: its *floor* (minimal feasible device
         fraction at max batch, ladder included — what the arbiter must
@@ -181,9 +202,15 @@ class AdaptiveServer:
         """
         if name in self.tenants:
             raise ValueError(f"tenant {name!r} already registered")
+        if net is None:
+            net = ChainNet(tuple(pool_window), activation)
+        if self.mesh is not None and net.kind != ChainNet.kind:
+            raise ValueError(
+                f"tenant {name!r}: a {net.kind} network is served on one "
+                f"device; sharded execution handles chains only")
         input_shape = tuple(int(d) for d in input_shape)
-        canonical = self._specs(params, (self.max_batch,) + input_shape,
-                                "float32", pool_window, activation, ladder)
+        canonical = self._specs(net, params, (self.max_batch,) + input_shape,
+                                "float32", ladder)
         # Admission check: both the max-batch and the one-sample graphs
         # must plan under the full device (raises the planner's
         # canonical error otherwise) — and both plans warm the share
@@ -195,13 +222,12 @@ class AdaptiveServer:
                      calibration=self.calibration)
         floor = network_min_fraction(canonical, self.budget)
         unit = plan_network(
-            self._specs(params, (1,) + input_shape, "float32",
-                        pool_window, activation, ladder),
+            self._specs(net, params, (1,) + input_shape, "float32", ladder),
             self.budget, fuse=self.fuse,
             calibration=self.calibration).calibrated_cycles(self.calibration)
         tenant = Tenant(name=name, params=params, input_shape=input_shape,
-                        pool_window=tuple(pool_window), activation=activation,
-                        ladder=tuple(ladder), measure_quant=measure_quant,
+                        net=net, ladder=tuple(ladder),
+                        measure_quant=measure_quant,
                         floor=floor, unit_cost=unit,
                         telemetry=TenantTelemetry(name=name,
                                                   max_batch=self.max_batch))
@@ -226,10 +252,8 @@ class AdaptiveServer:
         return self._guards.get(name)
 
     @staticmethod
-    def _specs(params, batch_shape, dtype, pool_window, activation, ladder):
-        return tuple(cnn_frontend_site_specs(
-            params, batch_shape, dtype, pool_window=tuple(pool_window),
-            activation=activation, ladder=tuple(ladder)))
+    def _specs(net, params, batch_shape, dtype, ladder):
+        return net.site_specs(params, batch_shape, dtype, tuple(ladder))
 
     def submit(self, name: str, x, *, at: Optional[float] = None):
         """Queue one sample (H, W, C) — or a (B, H, W, C) stack, queued
@@ -354,14 +378,14 @@ class AdaptiveServer:
         skey = (tenant.name, tuple(batch_shape), str(dtype), ladder)
         specs = self._specs_cache.get(skey)
         if specs is None:
-            specs = self._specs(tenant.params, batch_shape, dtype,
-                                tenant.pool_window, tenant.activation,
-                                ladder)
+            specs = self._specs(tenant.net, tenant.params, batch_shape,
+                                dtype, ladder)
             if len(self._specs_cache) >= _SIDE_CACHE_MAX:
                 self._specs_cache.pop(next(iter(self._specs_cache)))
             self._specs_cache[skey] = specs
         plan = replan(specs, slice_budget, fuse=self.fuse,
                       calibration=self.calibration, mesh=tenant_mesh)
+        tenant.telemetry.plans[batch_shape[0]] = plan
         return specs, slice_budget, tenant_mesh, plan
 
     def plan_for(self, name: str, batch: int):
@@ -428,13 +452,11 @@ class AdaptiveServer:
                 xb = jnp.stack(xs)
                 if device is not None:
                     xb = jax.device_put(xb, device)
-                y = apply_cnn_frontend(tenant.params, xb, network=plan,
-                                       pool_window=tenant.pool_window,
-                                       activation=tenant.activation,
-                                       ladder=ladder,
-                                       quant_report=quant_report,
-                                       tile_overrides=tile_overrides,
-                                       fuse=self.fuse)
+                y = serve_frontend(tenant.net, tenant.params, xb,
+                                   network=plan, ladder=ladder,
+                                   quant_report=quant_report,
+                                   tile_overrides=tile_overrides,
+                                   fuse=self.fuse)
         if INJECTOR.enabled:
             poisoned = INJECTOR.perturb_output("output", y, tenant.name)
             if poisoned is not y:
@@ -452,8 +474,8 @@ class AdaptiveServer:
         the batch output and its per-frame rows.  Keyed on what
         execution reads — the plan's per-site choices, not the budget
         that produced them — so a grant move that keeps every choice
-        re-uses the compiled step.  The tenant fixes the weights' shapes,
-        the pool window and the activation.  The weights are arguments,
+        re-uses the compiled step.  The tenant fixes the weights' shapes
+        and its network description.  The weights are arguments,
         not constants of the executable.  Returns
         ``(step, "hit"|"miss")``."""
         key = (tenant.name, batch_shape, str(dtype), ladder,
@@ -468,13 +490,11 @@ class AdaptiveServer:
             tenant.telemetry.step_cache_hits += 1
             return step, "hit"
         tenant.telemetry.step_cache_misses += 1
-        pool_window, activation = tenant.pool_window, tenant.activation
+        net = tenant.net
 
         def run(params, xs):
-            y = apply_cnn_frontend(params, jnp.stack(xs), network=plan,
-                                   pool_window=pool_window,
-                                   activation=activation, ladder=ladder,
-                                   tile_overrides=tile_overrides)
+            y = serve_frontend(net, params, jnp.stack(xs), network=plan,
+                               ladder=ladder, tile_overrides=tile_overrides)
             return y, tuple(y[i] for i in range(len(xs)))
 
         step = jax.jit(run)
@@ -596,10 +616,9 @@ class AdaptiveServer:
         dplan = plan.device_plan()
 
         def device_fn(xg):
-            return apply_cnn_frontend(tenant.params, xg, network=dplan,
-                                      pool_window=tenant.pool_window,
-                                      activation=tenant.activation,
-                                      tile_overrides=tile_overrides)
+            return serve_frontend(tenant.net, tenant.params, xg,
+                                  network=dplan,
+                                  tile_overrides=tile_overrides)
 
         fn = shard_map(device_fn, mesh=mesh,
                        in_specs=(P(plan.mesh.axis),),
@@ -659,9 +678,8 @@ class AdaptiveServer:
                                                  devices=n_dev)
                 for b in range(1, self.max_batch + 1):
                     specs = self._specs(
-                        tenant.params, (b,) + tenant.input_shape,
-                        "float32", tenant.pool_window, tenant.activation,
-                        tenant.ladder)
+                        tenant.net, tenant.params, (b,) + tenant.input_shape,
+                        "float32", tenant.ladder)
                     plan_network(specs, self.budget, fuse=self.fuse,
                                  calibration=self.calibration,
                                  mesh=spare_mesh if n_dev > 1 else None)

@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 from collections import deque
-from typing import Deque, Dict, List
+from typing import Any, Deque, Dict, List
 
 from repro.obs.metrics import percentile
 
@@ -78,6 +78,8 @@ class TenantTelemetry:
     guard_shed: int = 0
     guard_retries: int = 0
     degradations: int = 0
+    # batch size -> the plan that batch size last ran (AdaptiveServer._plan)
+    plans: Dict[int, Any] = dataclasses.field(default_factory=dict)
 
     def record_batch(self, batch_size: int, latencies: List[float],
                      plan, *, cache_hits: int, cache_misses: int,

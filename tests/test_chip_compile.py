@@ -236,3 +236,21 @@ def test_planner_never_offers_a_refused_rung(monkeypatch):
             plan_network((spec,), budget)
     finally:
         clear_plan_cache()
+
+
+def test_resnet50_step_compiles(one_chip, compiled_kernels):
+    """ResNet-50 v1.5 at 224 px and batch 4, served through the planner:
+    the stem's 7x7/2 unit, the strided and padded 3x3s, the strided 1x1
+    projections and every residual unit compile as ``res_conv`` kernels
+    in one step, beside the padded 3x3/2 max pool."""
+    from repro.models.frontends import ResidualNet, init_resnet
+    from repro.runtime.server import serve_frontend
+    params = jax.eval_shape(lambda: init_resnet(jax.random.PRNGKey(0)))
+    net = ResidualNet()
+    plan = plan_network(net.site_specs(params, IMAGE, "float32"),
+                        ResourceBudget())
+    x = jax.ShapeDtypeStruct(IMAGE, jnp.float32, sharding=one_chip)
+    text = _compile(functools.partial(serve_frontend, net, network=plan),
+                    _on(one_chip, params), x)
+    assert text.count('custom_call_target="tpu_custom_call"') == 54
+    assert text.count("%res_conv") >= 53

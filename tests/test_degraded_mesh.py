@@ -186,8 +186,8 @@ def test_prewarm_then_degrade_replans_nothing_cold():
     t = srv.tenants["a"]
     # registration warmed b=1 and b=4 (non-mesh, full budget); the
     # intermediate batch shapes are cold until prewarm fills them
-    specs_b3 = srv._specs(t.params, (3,) + t.input_shape, "float32",
-                          t.pool_window, t.activation, t.ladder)
+    specs_b3 = srv._specs(t.net, t.params, (3,) + t.input_shape, "float32",
+                          t.ladder)
     assert not plan_cache_contains(specs_b3, srv.budget, fuse=srv.fuse)
     warmed = srv.prewarm_spares(losses=1)
     assert warmed >= srv.max_batch
@@ -198,8 +198,8 @@ def test_prewarm_then_degrade_replans_nothing_cold():
     assert affected == ["a"]
     assert srv.mesh.devices == 1 and srv.arbiter.devices_for("a") == 1
     for b in range(1, srv.max_batch + 1):
-        specs = srv._specs(t.params, (b,) + t.input_shape, "float32",
-                           t.pool_window, t.activation, t.ladder)
+        specs = srv._specs(t.net, t.params, (b,) + t.input_shape, "float32",
+                           t.ladder)
         replan(specs, srv.arbiter.budget_for("a"), fuse=srv.fuse,
                mesh=srv.arbiter.mesh_for("a"))
     assert cold_replans_since(before) == 0
@@ -212,8 +212,8 @@ def test_degraded_plan_keeps_full_precision():
     never the precision bits."""
     srv = _mesh_server(max_batch=2)
     t = srv.tenants["a"]
-    specs = srv._specs(t.params, (2,) + t.input_shape, "float32",
-                       t.pool_window, t.activation, t.ladder)
+    specs = srv._specs(t.net, t.params, (2,) + t.input_shape, "float32",
+                       t.ladder)
     p2 = plan_network(specs, srv.arbiter.budget_for("a"), fuse=srv.fuse,
                       mesh=srv.arbiter.mesh_for("a"))
     srv.on_device_loss(1)

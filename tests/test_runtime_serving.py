@@ -419,8 +419,8 @@ def test_server_prices_and_accounts_under_calibration(rng):
     assert srv_raw.telemetry()["t"]["calibration_key"] is None
     # unit cost (the arbiter's demand weight) is the calibrated price
     tenant = srv_cal.tenants["t"]
-    specs = srv_cal._specs(params, (1, 12, 12, 6), "float32", (2, 2),
-                           "relu", ())
+    specs = srv_cal._specs(tenant.net, params, (1, 12, 12, 6), "float32",
+                           ())
     want = plan_network(specs, srv_cal.budget,
                         calibration=table).calibrated_cycles(table)
     assert tenant.unit_cost == pytest.approx(want)
