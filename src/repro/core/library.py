@@ -171,8 +171,9 @@ RES_CONV.register(KernelIP(
     supports_dtypes=("float32",), compiled_dtypes=("float32",),
     tags=("fused", "residual", "analogue:Conv2"),
     description="Residual unit in one launch: one MXU dot per tap and "
-                "conv row (strided, zero-padded), then bias, shortcut add "
-                "and ReLU on the VMEM-resident row; single HBM write."))
+                "conv row (strided, zero-padded), a 1x1 unit one dot per "
+                "row block, then bias, shortcut add and ReLU in VMEM; "
+                "single HBM write."))
 
 # --------------------------------------------------------------------------
 # matmul family — the LM-hot-path generalization.

@@ -12,6 +12,7 @@ The topology is described inside a fixture, never on import: only the
 worker that runs this file loads the TPU library.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -242,7 +243,11 @@ def test_resnet50_step_compiles(one_chip, compiled_kernels):
     """ResNet-50 v1.5 at 224 px and batch 4, served through the planner:
     the stem's 7x7/2 unit, the strided and padded 3x3s, the strided 1x1
     projections and every residual unit compile as ``res_conv`` kernels
-    in one step, beside the padded 3x3/2 max pool."""
+    in one step, beside the padded 3x3/2 max pool.  The 36 1x1 units
+    run the flat body (``res_conv_1x1``), and merging their pixels adds
+    no XLA copy or transpose: the step holds the 4 copies (the input
+    image's and three weights' layouts) and no transpose it held when
+    every unit ran the row body."""
     from repro.models.frontends import ResidualNet, init_resnet
     from repro.runtime.server import serve_frontend
     params = jax.eval_shape(lambda: init_resnet(jax.random.PRNGKey(0)))
@@ -254,3 +259,6 @@ def test_resnet50_step_compiles(one_chip, compiled_kernels):
                     _on(one_chip, params), x)
     assert text.count('custom_call_target="tpu_custom_call"') == 54
     assert text.count("%res_conv") >= 53
+    assert len(re.findall(r"%res_conv_1x1[.\d]* = ", text)) == 36
+    assert text.count(" copy(") <= 4
+    assert text.count(" transpose(") == 0

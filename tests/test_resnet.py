@@ -5,7 +5,9 @@ groups, and the network description a tenant registers.  The served
 network against the benchmark's reference is in
 ``tests/bench/test_bench_resnet.py``.
 """
+import functools
 import json
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +41,18 @@ CASES = [  # (n, h, cin, k, cout, stride, padding, residual, relu)
     (1, 11, 3, 7, 8, 2, 3, False, True),
     (1, 8, 16, 1, 8, 2, 0, False, False),
     (1, 7, 8, 1, 16, 1, 0, True, True),
+    # the flat 1x1 body: 7x7 blocks, whose 49 pixels are no multiple of 8
+    (1, 7, 8, 1, 16, 1, 0, False, True),
+    (3, 7, 8, 1, 16, 1, 0, True, False),
+    # Cin over one lane tile
+    (1, 7, 256, 1, 8, 1, 0, True, True),
+    # three Cout tiles, the input resident across them
+    (1, 7, 16, 1, 384, 1, 0, True, True),
+    # three Cout tiles over four row blocks, the weights resident
+    (2, 10, 8, 1, 384, 1, 0, False, False),
+    # a strided projection on an odd input (7 -> 4)
+    (2, 7, 16, 1, 32, 2, 0, False, False),
+    (1, 7, 16, 1, 32, 2, 0, True, True),
 ]
 
 
@@ -55,6 +69,87 @@ def test_res_conv_matches_conv_bias_add_relu(case):
     want = _ref(x, w, b, r, stride, padding, relu)
     assert got.shape == want.shape
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-5)
+
+
+def test_flat_unit_is_priced_by_its_grid_order():
+    """A 1x1 unit's bytes follow the grid order the flat body takes: the
+    input resident across the Cout tiles (weights read per row block),
+    or the weights resident (input read per Cout tile), whichever moves
+    fewer bytes; its MXU cycles are one dot per grid step."""
+    from repro.core.resources import cost_cycles, mxu_pass_cycles
+    from repro.kernels.conv2d.inner import conv_mxu_cycles
+    from repro.kernels.fused.res_conv import _hbm, footprint
+
+    def hbm(n, h, cin, cout):
+        return _hbm(n, h, h, cin, 1, 1, cout, 1, 0, itemsize=4,
+                    block_cout=128, residual=False)
+    out = 16 * 16 * 256 * 4
+    # 2 row blocks of 8 rows, 2 Cout tiles: the input resident
+    assert hbm(1, 16, 8, 256) == (16 * 16 * 8 + 2 * 8 * 256) * 4 \
+        + 256 * 4 + out
+    # 4 row blocks of 5 rows, 3 Cout tiles: the weights resident
+    out = 2 * 10 * 10 * 384 * 4
+    assert hbm(2, 10, 8, 384) == (3 * 2 * 10 * 10 * 8 + 8 * 384) * 4 \
+        + 384 * 4 + out
+    # one (7 rows x 32, 512) x (512, 128) dot a step, 4 row blocks x 4
+    # images, 6 bf16 passes at HIGHEST: under a third of the row body's
+    # 112 dots of one (32, 512) row each
+    flat = 16 * 6 * mxu_pass_cycles(224, 512, 128)
+    assert cost_cycles(flat, hbm(4, 28, 512, 128)) == footprint(
+        4, 28, 28, 512, 1, 1, 128).est_cycles
+    assert 3 * flat < conv_mxu_cycles(4, 28, 28, 512, 1, 1, 128,
+                                      itemsize=4, block_cout=128)
+
+
+def _pallas_calls(jaxpr):
+    """The ``name`` of every ``pallas_call`` in ``jaxpr`` and the jaxprs
+    it calls."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(str(eqn.params["name"]))
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names += _pallas_calls(sub)
+    return names
+
+
+def test_served_step_runs_each_1x1_unit_on_the_flat_body(small_params):
+    """The small ResNet's served step holds one ``res_conv_1x1`` call
+    per 1x1 unit and the row body for the rest; the chain steps hold
+    none."""
+    from repro.models.frontends import (cnn_frontend_site_specs,
+                                        init_cnn_frontend)
+    from repro.runtime.server import serve_frontend
+    shape = (2, 32, 32, 3)
+    net = ResidualNet()
+    plan = plan_network(net.site_specs(small_params, shape, "float32"))
+    names = _pallas_calls(jax.make_jaxpr(functools.partial(
+        serve_frontend, net, network=plan))(
+            small_params, jax.ShapeDtypeStruct(shape, jnp.float32)).jaxpr)
+    # 5 blocks: c1 and c3 each, and the 4 projections, are 1x1; the stem
+    # and the 5 c2 are not
+    assert names.count("res_conv_1x1") == 5 * 2 + 4
+    assert names.count("res_conv") == 1 + 5
+    # the benchmark's VGG-16 (configuration D) and LeNet-5 chains
+    configs = Path(__file__).resolve().parents[1] / "bench" / "configs"
+    for name in ("vgg16_d", "lenet5"):
+        cfg = json.loads((configs / f"{name}.json").read_text())
+        params = jax.eval_shape(lambda: init_cnn_frontend(
+            jax.random.PRNGKey(0), channels=tuple(cfg["channels"]),
+            k=cfg["kernel"], d_model=cfg["d_model"]))
+        chain = ChainNet(tuple(cfg["pool_window"]), cfg["activation"])
+        batch = (4, *cfg["image"])
+        plan = plan_network(cnn_frontend_site_specs(
+            params, batch, jnp.float32, pool_window=chain.pool_window,
+            activation=chain.activation))
+        names = _pallas_calls(jax.make_jaxpr(functools.partial(
+            serve_frontend, chain, network=plan))(
+                params, jax.ShapeDtypeStruct(batch, jnp.float32)).jaxpr)
+        assert names and "res_conv_1x1" not in names, name
 
 
 @pytest.mark.parametrize("ip", ["ip1_vpu", "ip2_mxu"])
